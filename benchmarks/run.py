@@ -4,7 +4,8 @@
         [--smoke] [--json BENCH_out.json]
 
 Prints ``name,us_per_call,derived`` CSV rows (any failure becomes a
-``<fig>/ERROR`` row — CI greps for those), and with ``--json`` also writes
+``<fig>/ERROR`` row, and the process exits non-zero once every figure has
+run and the JSON is written), and with ``--json`` also writes
 the schema'd artifact CI uploads for the perf trajectory (schema documented
 in benchmarks/README.md, validated here before writing). ``--smoke``
 shrinks every sweep to seconds for the CI bench-smoke job. `roofline` reads
@@ -22,6 +23,8 @@ import platform
 import subprocess
 import sys
 import time
+
+from repro.runtime.compile_cache import enable_compile_cache
 
 ALL = ("fig5", "fig6", "fig7", "fig14", "fig14_wall", "fig15", "fig16",
        "fig_fleet", "fleet_serve", "fig_decode", "workloads", "fig_arena",
@@ -154,6 +157,7 @@ def main(argv=None) -> None:
     ap.add_argument("--json", default=None, metavar="PATH",
                     help="write the schema'd BENCH_*.json artifact here")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     which = list(dict.fromkeys(args.figs)) or list(ALL)
     for name in which:
         if name not in _MODULES:
@@ -176,6 +180,9 @@ def main(argv=None) -> None:
         with open(args.json, "w") as f:
             json.dump(doc, f, indent=1)
         print(f"# wrote {args.json}", flush=True)
+    failed = [name for name, cell in figs.items() if cell["status"] == "error"]
+    if failed:
+        raise SystemExit(f"benchmark figures failed: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

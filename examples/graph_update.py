@@ -7,9 +7,11 @@ structure is functionally real (pointers into an allocator-managed heap);
 throughput comes from the DPU cost model.
 """
 from repro.graphupd.workload import GraphConfig, compare_all
+from repro.runtime.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     cfg = GraphConfig()
     print(f"partition: {cfg.n_nodes} nodes, {cfg.n_edges_pre} pre-edges, "
           f"{cfg.n_edges_new} new edges (1:2, paper methodology)\n")

@@ -20,9 +20,11 @@ import numpy as np
 from repro.core import heap
 from repro.core import system as sysm
 from repro.core.api import initAllocator
+from repro.runtime.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     # --- 1. Table 2 facade --------------------------------------------------
     a = initAllocator(1 << 20)  # 1 MB per-core heap, PIM-malloc-SW kind
     p1 = a.pimMalloc(100)       # thread-cache hit (128 B class)

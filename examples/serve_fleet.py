@@ -23,9 +23,11 @@ import argparse
 from repro.core import system as sysm
 from repro.launch.elastic import ElasticFleetServe, FaultPlan, MigrationConfig
 from repro.launch.serve_fleet import FleetServe, TrafficConfig
+from repro.runtime.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--ranks", type=int, default=2)
     ap.add_argument("--cores", type=int, default=2)

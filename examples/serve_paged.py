@@ -11,8 +11,10 @@ threaded through ArchConfig.attend_impl (no module globals).
 import sys
 
 from repro.launch import serve
+from repro.runtime.compile_cache import enable_compile_cache
 
 if __name__ == "__main__":
+    enable_compile_cache()
     sys.argv = [sys.argv[0], "--arch", "granite_3_8b", "--reduced",
                 "--batch", "4", "--prompt-len", "32", "--decode-steps", "48",
                 "--impl", "kernel", "--fleet-ranks", "2"]

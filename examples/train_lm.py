@@ -9,8 +9,10 @@ import shutil
 import sys
 
 from repro.launch import train
+from repro.runtime.compile_cache import enable_compile_cache
 
 if __name__ == "__main__":
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=200)
     args, _ = ap.parse_known_args()

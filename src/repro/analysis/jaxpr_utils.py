@@ -2,7 +2,7 @@
 
 Everything here operates on the closed jaxprs produced by
 `jax.make_jaxpr` over a backend step: recursive equation iteration
-through every higher-order primitive (scan / while / cond / pjit /
+through every higher-order primitive (scan / while / cond / jit /
 custom-derivative calls / pallas_call), producer maps, and small
 provenance / taint dataflow helpers. The passes in
 `repro.analysis.passes` are thin rule sets over these.

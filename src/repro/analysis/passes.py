@@ -113,19 +113,19 @@ _BOUND_PRIMS = frozenset({
     "shift_right_logical", "shift_right_arithmetic",
     "reduce_min", "reduce_max", "argmin", "argmax", "sort",
 })
-# jnp helpers that lower to pjit-wrapped sub-jaxprs; identified by name
-_BOUND_PJIT_NAMES = frozenset({
+# jnp helpers that lower to jit-wrapped sub-jaxprs; identified by name
+_BOUND_JIT_NAMES = frozenset({
     "clip", "_clip", "remainder", "mod", "argsort", "searchsorted",
 })
 _DISJOINT_PRIMS = frozenset({"iota"})
-_DISJOINT_PJIT_NAMES = frozenset({"argsort"})  # permutations never collide
+_DISJOINT_JIT_NAMES = frozenset({"argsort"})  # permutations never collide
 
 
 def _is_bounding(eqn, tainted) -> bool:
     name = eqn.primitive.name
     if name in _BOUND_PRIMS:
         return True
-    if name == "pjit" and eqn.params.get("name") in _BOUND_PJIT_NAMES:
+    if name == "jit" and eqn.params.get("name") in _BOUND_JIT_NAMES:
         return True
     if name == "convert_element_type":
         src = getattr(eqn.invars[0].aval, "dtype", None)
@@ -137,7 +137,7 @@ def _is_bounding(eqn, tainted) -> bool:
     # select_n(idx < 0, idx, idx + N) — has BOTH branches tainted and is
     # deliberately NOT a guard.
     if (name == "select_n"
-            or (name == "pjit" and eqn.params.get("name") == "_where")):
+            or (name == "jit" and eqn.params.get("name") == "_where")):
         data_ops = eqn.invars[1:]   # operand 0 is the predicate
         if any(isinstance(v, Literal) or v not in tainted
                for v in data_ops):
@@ -156,8 +156,8 @@ def _disjoint_witness(jaxpr, var, prods) -> bool:
     return derives_from(
         jaxpr, var,
         lambda e: (e.primitive.name in _DISJOINT_PRIMS
-                   or (e.primitive.name == "pjit"
-                       and e.params.get("name") in _DISJOINT_PJIT_NAMES)),
+                   or (e.primitive.name == "jit"
+                       and e.params.get("name") in _DISJOINT_JIT_NAMES)),
         prods)
 
 
@@ -267,7 +267,7 @@ def check_int_width(tr: TracedStep, _ctx=None):
     div_guarded = set()
     for eqn in tr.jaxpr.eqns:
         name = eqn.primitive.name
-        if name == "div" or (name == "pjit"
+        if name == "div" or (name == "jit"
                              and eqn.params.get("name") == "floor_divide"):
             div_guarded.update(v for v in eqn.invars if isinstance(v, Var))
     for eqn in tr.jaxpr.eqns:
@@ -322,7 +322,7 @@ def check_write_race(tr: TracedStep, _ctx=None):
     # only the top level: eqns inside scan/while run in the serialized
     # mutex region (one thread per iteration) and cannot race
     for eqn, path in iter_eqns(tr.jaxpr, skip_prims=ju.SERIAL_PRIMS):
-        if path:  # nested in pjit etc.: vars are scoped, skip
+        if path:  # nested in jit etc.: vars are scoped, skip
             continue
         if eqn.primitive.name != "scatter":   # scatter-add is commutative
             continue

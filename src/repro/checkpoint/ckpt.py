@@ -107,8 +107,18 @@ def restore(tree_like, step: int, ckpt_dir: str, shardings=None):
                     f"lossy dtype cast restoring {key!r}: saved "
                     f"{arr.dtype} -> wanted {want}")
             arr = cast
+        narrowed = jax.dtypes.canonicalize_dtype(want) != want
         if flat_sh is not None:
+            if narrowed:
+                # device_put would narrow it to 32 bits without a word
+                raise ValueError(
+                    f"cannot place {key!r} ({want}) on a sharding with "
+                    f"jax_enable_x64 off: it would be narrowed to 32 bits")
             out[key] = jax.device_put(arr, flat_sh[key])
+        elif narrowed:
+            # a 64-bit leaf with jax_enable_x64 off: a jax array would
+            # narrow it to 32 bits, so it stays a host array
+            out[key] = arr
         else:
             out[key] = jax.numpy.asarray(arr)
     # rebuild tree

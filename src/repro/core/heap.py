@@ -358,7 +358,6 @@ def sharded_inner(cfg, num_ranks: int, mesh=None, axis_name: str = "ranks"):
         mesh = make_rank_mesh(num_ranks, axis_name)
     if mesh is False:
         return inner, None
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec
     axis_name = mesh.axis_names[0]
     if num_ranks % mesh.shape[axis_name]:
@@ -366,8 +365,8 @@ def sharded_inner(cfg, num_ranks: int, mesh=None, axis_name: str = "ranks"):
             f"num_ranks={num_ranks} not divisible by mesh axis "
             f"{axis_name}={mesh.shape[axis_name]}")
     spec = PartitionSpec(axis_name)
-    return shard_map(inner, mesh=mesh, in_specs=(spec, spec),
-                     out_specs=(spec, spec), check_rep=False), mesh
+    return jax.shard_map(inner, mesh=mesh, in_specs=(spec, spec),
+                         out_specs=(spec, spec), check_vma=False), mesh
 
 
 class ShardedHeap:
@@ -382,9 +381,10 @@ class ShardedHeap:
     the state buffers in place instead of an O(heap) copy per protocol round
     (backends without donation, e.g. CPU, silently fall back to copying).
 
-    ``mesh=None`` builds a 1-D mesh over the local devices (1-device on CPU
-    CI — the whole path still compiles through shard_map); ``mesh=False``
-    skips shard_map entirely and runs the pure vmap fallback. Both must be
+    ``mesh=None`` builds a 1-D mesh over every device (1-device on CPU CI —
+    the whole path still compiles through shard_map) and raises ValueError
+    unless the device count divides ``num_ranks``; ``mesh=False`` skips
+    shard_map entirely and runs the pure vmap fallback. Both must be
     bitwise-identical to :class:`MultiCoreHeap` per (rank, core) — pinned in
     tests/test_sharded_heap.py.
     """

@@ -529,10 +529,18 @@ def fused_heap_step(op, size, ptr, longest, counts, stacks, block_cls,
     resolves from ``PIM_MALLOC_BATCH_REFILL`` (default on); both settings
     are bitwise-identical, ``False`` merely forces the pre-batching serial
     walk (the wall-clock bench lane's comparison point).
+
+    ``interpret=None`` interprets off the TPU and compiles on it. The
+    interpreter never runs on a TPU: asking for it there raises. The
+    compiled kernel does not lower for v5e yet (ROADMAP A2).
     """
+    from repro.kernels.ops import on_tpu
     if interpret is None:
-        from repro.kernels.ops import on_tpu
         interpret = not on_tpu()
+    elif interpret and on_tpu():
+        raise NotImplementedError(
+            "fused_heap_step: interpret mode is refused on a TPU backend; "
+            "the compiled kernel does not lower for TPU yet (ROADMAP A2)")
     if batch_refill is None:
         batch_refill = _batch_refill_default()
     return _fused_heap_step(

@@ -164,7 +164,6 @@ def write_attend_seqpar(q, k_new, v_new, k_pages, v_pages, page_table, pos):
         vp = write_token(v_pages, v_new, page_table, pos)
         return attend(q, kp, vp, page_table, pos + 1), kp, vp
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     B, H, hd = q.shape
@@ -218,12 +217,12 @@ def write_attend_seqpar(q, k_new, v_new, k_pages, v_pages, page_table, pos):
         return o.reshape(Bl, H, hd).astype(q.dtype), kp, vp
 
     pool_spec = P(dpb, "model", None, None, None)
-    o, kp, vp = shard_map(
+    o, kp, vp = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(dpb, None, None), P(dpb, None, None), P(dpb, None, None),
                   pool_spec, pool_spec, P(dpb, None), P(dpb,)),
         out_specs=(P(dpb, None, None), pool_spec, pool_spec),
-        check_rep=False,
+        check_vma=False,
     )(q, k_new, v_new, k_pages, v_pages, page_table, pos)
     return o, kp, vp
 

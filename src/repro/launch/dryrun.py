@@ -32,7 +32,6 @@ from repro.models import registry
 from repro.models.config import SHAPES
 from repro.optim.adamw import AdamWConfig
 from repro.parallel import sharding
-from repro.parallel.meshctx import activate_mesh
 
 RESULTS_DIR = "results/dryrun"
 
@@ -106,7 +105,7 @@ def dryrun_cell(arch: str, shape_name: str, multi_pod: bool = False,
     t0 = time.time()
     def nm_(spec):
         return sharding.named(mesh, spec)
-    with activate_mesh(mesh):
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             opt_cfg = AdamWConfig(moment_dtype=cfg.opt_moment_dtype)
             nm = (n_micro or cfg.train_microbatches

@@ -78,14 +78,8 @@ def _shape_elems(shape_str: str) -> int:
 
 
 def cost_analysis_dict(compiled) -> dict:
-    """`compiled.cost_analysis()` as one flat dict across jax versions.
-
-    jax <= 0.4.x returns a one-element list of per-program dicts; newer
-    versions return the dict directly."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca)
+    """`compiled.cost_analysis()` as a plain dict."""
+    return dict(compiled.cost_analysis())
 
 
 class Instruction:

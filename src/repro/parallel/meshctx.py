@@ -1,73 +1,36 @@
-"""Version-portable mesh activation & discovery.
+"""Mesh discovery and the fleet's rank mesh.
 
-jax has renamed the "make this mesh ambient" entry point three times:
-
-  * jax >= 0.8   : ``jax.set_mesh(mesh)`` (context manager)
-  * jax ~ 0.5-0.7: ``jax.sharding.use_mesh(mesh)``
-  * jax 0.4.x    : ``with mesh:`` (the Mesh resource-env context manager)
-
-and likewise for reading it back (``jax.sharding.get_abstract_mesh`` vs the
-0.4.x thread-resources physical mesh). Every call site in this repo that
-activates or sniffs a mesh goes through this module so the whole tree runs
-unmodified across those versions (the CI container pins 0.4.x).
+``jax.set_mesh`` makes a mesh ambient; :func:`ambient_mesh` reads it back.
 """
 from __future__ import annotations
 
-import contextlib
-
 import jax
-
-
-def activate_mesh(mesh):
-    """Context manager making `mesh` the ambient mesh on any jax version."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    use_mesh = getattr(jax.sharding, "use_mesh", None)
-    if use_mesh is not None:
-        return use_mesh(mesh)
-    # jax 0.4.x: Mesh is itself the resource-env context manager
-    return mesh
+from jax.sharding import AxisType
 
 
 def ambient_mesh():
-    """The currently active mesh, or None. Mirrors `activate_mesh`."""
-    get_abstract = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_abstract is not None:
-        try:
-            mesh = get_abstract()
-            if mesh is not None and not mesh.empty:
-                return mesh
-        except Exception:
-            pass
-    try:  # jax 0.4.x thread-local resource env
-        from jax._src import mesh as _mesh_lib
-        mesh = _mesh_lib.thread_resources.env.physical_mesh
-        if not mesh.empty:
-            return mesh
-    except Exception:
-        pass
-    return None
-
-
-@contextlib.contextmanager
-def maybe_activate(mesh):
-    """`activate_mesh` that tolerates mesh=None (no-op)."""
-    if mesh is None:
-        yield None
-    else:
-        with activate_mesh(mesh) as m:
-            yield m
+    """The mesh made ambient by ``jax.set_mesh``, or None."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty else mesh
 
 
 def make_rank_mesh(num_ranks: int, axis_name: str = "ranks"):
-    """1-D device mesh for a `num_ranks`-rank fleet.
+    """1-D mesh over every device for a `num_ranks`-rank fleet.
 
-    Uses the largest device count that divides `num_ranks` so every device
-    carries the same number of rank shards; on a single-device host (CPU CI)
-    this degenerates to a 1-device mesh and the fleet runs fully local.
+    Each device carries the same number of rank shards, so the device count
+    must divide ``num_ranks``: anything else raises rather than quietly
+    spreading the fleet over fewer devices than there are.
+
+    The axis is ``AxisType.Auto``: the fleet step is shard_mapped over it,
+    and the slot file, request grids and response pytrees around that
+    shard_map stay ordinary arrays whose placement the compiler chooses
+    (an ``Explicit`` axis would type every response as rank-sharded and
+    refuse to mix it with them).
     """
-    n_dev = max(jax.device_count(), 1)
-    ranks = max(num_ranks, 1)
-    d = max(k for k in range(1, min(ranks, n_dev) + 1) if ranks % k == 0)
-    return jax.make_mesh((d,), (axis_name,))
+    n_dev = jax.device_count()
+    if num_ranks % n_dev:
+        raise ValueError(
+            f"num_ranks={num_ranks} cannot be split evenly over the "
+            f"{n_dev} devices; use a multiple of {n_dev} ranks, pass a mesh "
+            f"of your own, or mesh=False")
+    return jax.make_mesh((n_dev,), (axis_name,), axis_types=(AxisType.Auto,))

@@ -1,22 +1,7 @@
 """Shared test plumbing: degrade hypothesis property tests to skips when
 hypothesis is not installed, instead of failing collection of the whole file
-(the non-property tests in the same modules still run).
-
-Also pins the XLA CPU runtime for the whole suite: jaxlib 0.4.37's new
-thunk-based CPU runtime leaks per-compilation state, and a full tier-1 run
-eagerly compiles enough distinct programs (~300 tests x several backends)
-that the process segfaults inside ``backend_compile`` around the 75% mark
-— deterministically, but at whichever compile happens to cross the
-threshold. The legacy runtime is stable at this volume. Must be set before
-jax initializes its backends, hence conftest import time."""
-import os
-
-_xla_flags = os.environ.get("XLA_FLAGS", "")
-if "--xla_cpu_use_thunk_runtime" not in _xla_flags:
-    os.environ["XLA_FLAGS"] = (
-        _xla_flags + " --xla_cpu_use_thunk_runtime=false").strip()
-
-import pytest  # noqa: E402
+(the non-property tests in the same modules still run)."""
+import pytest
 
 
 def hypothesis_or_skip():

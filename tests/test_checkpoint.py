@@ -148,6 +148,21 @@ def test_restore_refuses_lossy_dtype_cast(tmp_path):
         ckpt.restore(want, 0, str(tmp_path), shardings={"x": sharding})
 
 
+def test_restore_keeps_64_bit_leaves_or_refuses_a_sharding(tmp_path):
+    """With jax_enable_x64 off, a 64-bit leaf restores as a host array with
+    its values intact; asked to place it on a sharding, restore raises
+    rather than let device_put narrow it to 32 bits."""
+    assert not jax.config.jax_enable_x64
+    saved = {"x": np.array([1 << 40, -3], np.int64)}
+    ckpt.save(saved, 0, str(tmp_path))
+    back = ckpt.restore(saved, 0, str(tmp_path))
+    assert back["x"].dtype == np.int64
+    np.testing.assert_array_equal(back["x"], saved["x"])
+    sharding = jax.sharding.SingleDeviceSharding(jax.devices()[0])
+    with pytest.raises(ValueError, match="narrowed"):
+        ckpt.restore(saved, 0, str(tmp_path), shardings={"x": sharding})
+
+
 def test_restore_rejects_shape_mismatch(tmp_path):
     ckpt.save({"x": np.zeros((4,), np.int32)}, 0, str(tmp_path))
     with pytest.raises(AssertionError):

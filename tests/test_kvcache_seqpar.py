@@ -9,7 +9,6 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from repro.kvcache import paged
-from repro.parallel.meshctx import activate_mesh
 
 mesh = jax.make_mesh((2, 4), ("data", "model"))
 B, Pn, page, KVH, hd, H = 4, 8, 16, 2, 32, 4
@@ -27,7 +26,7 @@ kp_r = paged.write_token(kp, kn, pt, pos)
 vp_r = paged.write_token(vp, vn, pt, pos)
 o_r = paged.attend(q, kp_r, vp_r, pt, pos + 1)
 
-with activate_mesh(mesh):
+with jax.set_mesh(mesh):
     o_s, kp_s, vp_s = jax.jit(lambda *a: paged.write_attend_seqpar(*a))(
         q, kn, vn, kp, vp, pt, pos)
 np.testing.assert_allclose(np.asarray(o_s), np.asarray(o_r), atol=3e-5,

@@ -319,3 +319,24 @@ def test_env_stamp_ignores_untracked_pycache(tmp_path):
     (tmp_path / "f.py").write_text("x = 2\n")
     stamp = bench_run.env_stamp(True, root=str(tmp_path))
     assert stamp["commit"].endswith("-dirty")
+
+
+def test_run_exits_nonzero_after_writing_error_rows(tmp_path, monkeypatch,
+                                                    capsys):
+    """A figure that raises still gets its /ERROR row and JSON cell, and
+    then the harness exits non-zero."""
+    from benchmarks import run as bench_run
+
+    def broken(smoke):
+        raise RuntimeError("boom")
+
+    monkeypatch.setattr(bench_run, "enable_compile_cache", lambda: None)
+    monkeypatch.setattr(bench_run.importlib, "import_module",
+                        lambda *a, **k: type("M", (), {"bench": broken}))
+    out = tmp_path / "b.json"
+    with pytest.raises(SystemExit) as e:
+        bench_run.main(["fig5", "--smoke", "--json", str(out)])
+    assert e.value.code not in (0, None)
+    assert "fig5/ERROR" in capsys.readouterr().out
+    doc = json.loads(out.read_text())
+    assert doc["figs"]["fig5"]["status"] == "error"

@@ -177,3 +177,44 @@ def test_serve_fleet_page_requests():
     ptr = np.asarray(resp.ptr)
     assert int((ptr >= 0).sum()) == 3
     assert router.stats["per_rank"]["ops"] == [2, 1]
+
+
+RANK_MESH = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import jax
+from jax.sharding import AxisType
+from repro.core import heap, system as sysm
+from repro.parallel.meshctx import make_rank_mesh
+
+cfg = sysm.SystemConfig(kind="sw", heap_bytes=1 << 16, num_threads=4)
+for R in (6, 2, 1):
+    for build in (lambda: make_rank_mesh(R),
+                  lambda: heap.ShardedHeap(cfg, num_ranks=R, num_cores=2)):
+        try:
+            build()
+        except ValueError:
+            continue
+        raise SystemExit(f"R={R} over 4 devices did not raise")
+    assert heap.ShardedHeap(cfg, R, 2, mesh=False).mesh is None
+mesh = make_rank_mesh(8)
+assert mesh.devices.size == 4 and mesh.axis_types == (AxisType.Auto,)
+assert heap.ShardedHeap(cfg, num_ranks=8, num_cores=2).mesh.devices.size == 4
+print("rank-mesh-ok")
+"""
+
+
+def test_make_rank_mesh_raises_on_indivisible_request():
+    """With ``mesh=None`` a fleet whose rank count the device count does not
+    divide raises (make_rank_mesh and ShardedHeap alike) instead of quietly
+    spreading over fewer devices; ``mesh=False`` still serves it. Four
+    virtual CPU devices, in a subprocess so the XLA flag never leaks."""
+    import os
+    import subprocess
+    import sys
+    env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu")
+    out = subprocess.run([sys.executable, "-c", RANK_MESH], env=env,
+                         capture_output=True, text=True, timeout=300,
+                         cwd=os.path.dirname(os.path.dirname(__file__)))
+    assert out.returncode == 0, out.stderr[-3000:]
+    assert "rank-mesh-ok" in out.stdout

@@ -71,9 +71,7 @@ def test_error_feedback_accumulates():
 
 
 def test_compressed_psum_matches_fp32():
-    from jax.sharding import Mesh
     import jax
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = jax.make_mesh((1,), ("data",))
@@ -82,7 +80,8 @@ def test_compressed_psum_matches_fp32():
     def f(xs):
         return compression.compressed_psum(xs[0], "data")[None]
 
-    y = shard_map(f, mesh=mesh, in_specs=P("data"), out_specs=P("data"))(x)
+    y = jax.shard_map(f, mesh=mesh, in_specs=P("data"),
+                      out_specs=P("data"))(x)
     np.testing.assert_allclose(np.asarray(y)[0], np.asarray(x)[0], atol=0.1,
                                rtol=0.02)
 
