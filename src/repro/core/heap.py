@@ -328,9 +328,10 @@ class MultiCoreHeap:
 # ---------------------------------------------------------------------------
 def sharded_init(cfg, num_ranks: int, num_cores: int, prepopulate: bool = True):
     """Stacked fleet state: every leaf gains leading [R, C] axes."""
-    st = multicore_init(cfg, num_cores, prepopulate=prepopulate)
-    return jax.tree.map(
-        lambda x: jnp.broadcast_to(x[None], (num_ranks,) + x.shape), st)
+    with jax.named_scope("init"):
+        st = multicore_init(cfg, num_cores, prepopulate=prepopulate)
+        return jax.tree.map(
+            lambda x: jnp.broadcast_to(x[None], (num_ranks,) + x.shape), st)
 
 
 def sharded_step(cfg, states, requests: AllocRequest):

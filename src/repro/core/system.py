@@ -338,50 +338,58 @@ def _protocol_round(cfg: SystemConfig, st: SystemState, req: AllocRequest,
     (3) one batched free round (FREE + released old realloc blocks), then a
     single metadata-cache pass + mutex queue over both phases' backend ops
     in serialization order (malloc phase drains first — mutex FIFO).
+    Each phase runs under a `jax.named_scope` (`realloc_meta`, `malloc`,
+    `free`, `cache_pass`, `price`), so its device ops carry its name.
     """
     op, size, ptr = req.op, req.size, req.ptr
     is_alloc = (op == OP_MALLOC) | (op == OP_CALLOC)
     is_re = op == OP_REALLOC
     is_free = op == OP_FREE
 
-    meta = meta_fn(st.alloc, ptr, size)
+    with jax.named_scope("realloc_meta"):
+        meta = meta_fn(st.alloc, ptr, size)
     re_live = is_re & (size > 0)
     in_place = re_live & meta.in_place
     moved = re_live & ~meta.in_place
     re_free0 = is_re & (size <= 0) & (ptr >= 0)
 
     # ---- phase 1: batched malloc (new blocks) ------------------------------
-    m_active = (is_alloc & (size > 0)) | moved
-    alloc_st, mptrs, mev = malloc_fn(st.alloc, jnp.where(m_active, size, 0),
-                                     m_active)
-    mok = m_active & (mptrs >= 0)
+    with jax.named_scope("malloc"):
+        m_active = (is_alloc & (size > 0)) | moved
+        alloc_st, mptrs, mev = malloc_fn(
+            st.alloc, jnp.where(m_active, size, 0), m_active)
+        mok = m_active & (mptrs >= 0)
 
     # ---- phase 2: batched free (explicit frees + vacated realloc blocks) ---
-    f_active = is_free | (moved & meta.valid_old & mok) | re_free0
-    alloc_st, fev = free_fn(alloc_st, jnp.where(f_active, ptr, INVALID),
-                            f_active)
-    fpath = free_path_fn(fev)
+    with jax.named_scope("free"):
+        f_active = is_free | (moved & meta.valid_old & mok) | re_free0
+        alloc_st, fev = free_fn(alloc_st, jnp.where(f_active, ptr, INVALID),
+                                f_active)
+        fpath = free_path_fn(fev)
 
     # ---- one cache pass + shared pricing over both phases ------------------
-    n_back_m = jnp.sum(mev.backend_pos >= 0)
-    bpos = jnp.concatenate([
-        mev.backend_pos,
-        jnp.where(fev.backend_pos >= 0, fev.backend_pos + n_back_m, INVALID),
-    ])
-    traces = jnp.concatenate([mev.trace, fev.trace], axis=0)
-    cache_st, tstats = _cache_pass(cfg, st.cache, bpos, traces)
+    with jax.named_scope("cache_pass"):
+        n_back_m = jnp.sum(mev.backend_pos >= 0)
+        bpos = jnp.concatenate([
+            mev.backend_pos,
+            jnp.where(fev.backend_pos >= 0, fev.backend_pos + n_back_m,
+                      INVALID),
+        ])
+        traces = jnp.concatenate([mev.trace, fev.trace], axis=0)
+        cache_st, tstats = _cache_pass(cfg, st.cache, bpos, traces)
     T = op.shape[0]
-    resp, alloc_bytes, freed_bytes = _price_round(
-        cfg, req, mptrs=mptrs, m_path=mev.path, m_bpos=mev.backend_pos,
-        m_lvdown=mev.levels_down, m_lvup=mev.levels_up, fpath=fpath,
-        f_bpos=fev.backend_pos, f_lvup=fev.levels_up,
-        hits_m=tstats.hits[:T], miss_m=tstats.misses[:T],
-        dram_m=tstats.dram_bytes[:T], hits_f=tstats.hits[T:],
-        miss_f=tstats.misses[T:], dram_f=tstats.dram_bytes[T:],
-        in_place=in_place, moved=moved, mok=mok, valid_old=meta.valid_old,
-        old_bytes=meta.old_bytes, new_bytes=meta.new_bytes,
-        re_free0=re_free0)
-    telem = _advance_telemetry(st.telem, alloc_bytes, freed_bytes)
+    with jax.named_scope("price"):
+        resp, alloc_bytes, freed_bytes = _price_round(
+            cfg, req, mptrs=mptrs, m_path=mev.path, m_bpos=mev.backend_pos,
+            m_lvdown=mev.levels_down, m_lvup=mev.levels_up, fpath=fpath,
+            f_bpos=fev.backend_pos, f_lvup=fev.levels_up,
+            hits_m=tstats.hits[:T], miss_m=tstats.misses[:T],
+            dram_m=tstats.dram_bytes[:T], hits_f=tstats.hits[T:],
+            miss_f=tstats.misses[T:], dram_f=tstats.dram_bytes[T:],
+            in_place=in_place, moved=moved, mok=mok,
+            valid_old=meta.valid_old, old_bytes=meta.old_bytes,
+            new_bytes=meta.new_bytes, re_free0=re_free0)
+        telem = _advance_telemetry(st.telem, alloc_bytes, freed_bytes)
     return SystemState(alloc=alloc_st, cache=cache_st, telem=telem), resp
 
 

@@ -35,6 +35,7 @@ from jax import lax
 from repro.core import heap as heap_api
 from repro.core import telemetry
 from repro.core.heap import OP_REALLOC, AllocRequest, AllocResponse
+from repro.runtime.spans import span
 from repro.workloads.trace import Trace
 
 PERCENTILES = (50, 95, 99)
@@ -190,21 +191,24 @@ class ScanEngine:
         return R * C * T
 
     def _round_body(self, n_slots: int, cap: int):
+        @jax.named_scope("round")
         def body(carry, x):
             st, slots = carry
             r, op_r, size_r, ref_r, raw_r = x
-            ptr = jnp.where(ref_r >= 0,
-                            slots[jnp.clip(ref_r, 0, n_slots - 1)], raw_r)
+            with jax.named_scope("slots"):
+                ptr = jnp.where(ref_r >= 0,
+                                slots[jnp.clip(ref_r, 0, n_slots - 1)], raw_r)
             st, resp = self._inner(st, AllocRequest(op=op_r, size=size_r,
                                                     ptr=ptr))
             # slot = the op's surviving pointer (same rule as the workloads
             # replayer): a failed relocating realloc keeps the old block,
             # so the tenant's scheduled expiry FREE must still reach it
-            survived = ((op_r == OP_REALLOC) & (size_r > 0)
-                        & (resp.ptr < 0) & (ptr >= 0))
-            slots = lax.dynamic_update_slice(
-                slots, jnp.where(survived, ptr, resp.ptr).reshape(-1),
-                (r * cap,))
+            with jax.named_scope("slots"):
+                survived = ((op_r == OP_REALLOC) & (size_r > 0)
+                            & (resp.ptr < 0) & (ptr >= 0))
+                slots = lax.dynamic_update_slice(
+                    slots, jnp.where(survived, ptr, resp.ptr).reshape(-1),
+                    (r * cap,))
             return (st, slots), resp
 
         return body
@@ -240,20 +244,34 @@ class ScanEngine:
     def run_segment(self, state, slots, r0: int, plan):
         """Execute rounds [r0, r1) of a planned session (r1 = r0 + segment
         length implied by the sliced grids passed via ``plan`` tuple
-        ``(op, size, ptr_ref, ptr_raw)``); returns (state, slots, resps)."""
-        op, size, ptr_ref, ptr_raw = plan
-        return self._segment(
-            state, slots, jnp.int32(r0), jnp.asarray(op), jnp.asarray(size),
-            jnp.asarray(ptr_ref), jnp.asarray(ptr_raw))
+        ``(op, size, ptr_ref, ptr_raw)``); returns (state, slots, resps).
+        Spans: `serve/segment` around `serve/h2d` and `serve/dispatch`, as
+        in :meth:`run`."""
+        with span("serve/segment", rounds=int(plan[0].shape[0]),
+                  h2d_bytes=sum(int(g.nbytes) for g in plan)):
+            with span("serve/h2d"):
+                grids = [jnp.asarray(g) for g in plan]
+            with span("serve/dispatch"):
+                return self._segment(state, slots, jnp.int32(r0), *grids)
 
     def run(self, plan):
         """Execute a planned session on a fresh fleet; returns the final
-        sharded state and the stacked [rounds, R, C, T] responses."""
-        state = heap_api.sharded_init(self.cfg, self.num_ranks,
-                                      self.num_cores)
-        return self._scan(
-            state, jnp.asarray(plan.op), jnp.asarray(plan.size),
-            jnp.asarray(plan.ptr_ref), jnp.asarray(plan.ptr_raw))
+        sharded state and the stacked [rounds, R, C, T] responses.
+
+        Spans (`repro.runtime.spans`): `serve/session` (counts `rounds` and
+        `h2d_bytes`, the plan grids' bytes) around `serve/init` (state
+        init), `serve/h2d` (the grids' copies) and `serve/dispatch` (the
+        scan's dispatch; its device work ends after the call returns)."""
+        grids = (plan.op, plan.size, plan.ptr_ref, plan.ptr_raw)
+        with span("serve/session", rounds=int(plan.op.shape[0]),
+                  h2d_bytes=sum(int(g.nbytes) for g in grids)):
+            with span("serve/init"):
+                state = heap_api.sharded_init(self.cfg, self.num_ranks,
+                                              self.num_cores)
+            with span("serve/h2d"):
+                grids = [jnp.asarray(g) for g in grids]
+            with span("serve/dispatch"):
+                return self._scan(state, *grids)
 
     # ------------------------------------------------------------------
     # tape export: one core's slice of a session is a standard trace
