@@ -219,8 +219,12 @@ def malloc(cfg: PimMallocConfig, st: PimMallocState, sizes, active=None):
     bypass = active & (sizes > cfg.max_class) & ~too_big
     need = refill | bypass
 
+    # `stacks` stays out of the carry: nothing here reads it and thread t
+    # writes only its own row, so the rows are carved after the scan. A row
+    # write in the step, at a per-core index under vmap, is a gather and a
+    # scatter that XLA expands into serial loops over the cores.
     def step(carry, x):
-        bstate, counts, stacks, block_cls, block_free, big_log2, border = carry
+        bstate, counts, block_cls, block_free, big_log2, border = carry
         t, need_t, refill_t, bypass_t, size_t, c_t = x
         alloc_size = jnp.where(
             bypass_t, next_pow2(jnp.maximum(size_t, cfg.block_bytes)),
@@ -237,12 +241,7 @@ def malloc(cfg: PimMallocConfig, st: PimMallocState, sizes, active=None):
         # -- refill: carve block into sub-blocks, push all, pop top ----------
         csize = class_sizes[c_t]
         sub = cfg.block_bytes // csize
-        offs = off + jnp.arange(cfg.max_sub, dtype=jnp.int32) * csize
-        row = jnp.where(jnp.arange(cfg.max_sub) < sub, offs, INVALID)
         do_refill = refill_t & ok
-        stacks = stacks.at[t, c_t, : cfg.max_sub].set(
-            jnp.where(do_refill, row, stacks[t, c_t, : cfg.max_sub])
-        )
         counts = counts.at[t, c_t].set(
             jnp.where(do_refill, sub - 1, counts[t, c_t])
         )
@@ -266,18 +265,30 @@ def malloc(cfg: PimMallocConfig, st: PimMallocState, sizes, active=None):
             bpos,
             ok,
         )
-        return (bstate, counts, stacks, block_cls, block_free, big_log2, border), (ptr, ev)
+        return (bstate, counts, block_cls, block_free, big_log2, border), (
+            ptr, off, ev)
 
-    carry = (st.buddy, counts, st.stacks, st.block_cls, block_free, st.big_log2,
+    carry = (st.buddy, counts, st.block_cls, block_free, st.big_log2,
              jnp.int32(0))
     xs = (t_idx, need, refill, bypass, sizes, c)
-    carry, (ptr_b, (lv_down, lv_up, trace, bpos, ok_b)) = lax.scan(step, carry, xs)
-    bstate, counts, stacks, block_cls, block_free, big_log2, _ = carry
+    carry, (ptr_b, off_b, (lv_down, lv_up, trace, bpos, ok_b)) = lax.scan(
+        step, carry, xs)
+    bstate, counts, block_cls, block_free, big_log2, _ = carry
+    refilled = refill & ok_b
+
+    # carve each refilled block into its thread's class-c freelist at once
+    slot = jnp.arange(cfg.cap, dtype=jnp.int32)
+    csize = class_sizes[c][:, None]
+    rows = jnp.where(slot < cfg.block_bytes // csize,
+                     off_b[:, None] + slot * csize, INVALID)       # [T, CAP]
+    write = refilled[:, None] & (c[:, None] == jnp.arange(cfg.nc))  # [T, NC]
+    stacks = jnp.where(write[:, :, None] & (slot < cfg.max_sub),
+                       rows[:, None, :], st.stacks)
 
     ptrs = jnp.where(hit, ptr_a, ptr_b)
     path = jnp.where(
         hit, 0,
-        jnp.where(refill & ok_b, 1,
+        jnp.where(refilled, 1,
                   jnp.where(bypass & ok_b, 2,
                             jnp.where(need | too_big, 3, INVALID))),
     ).astype(jnp.int32)

@@ -1,13 +1,16 @@
 """Tests for the hierarchical PIM-malloc-SW allocator (thread cache + buddy)."""
+import functools
 import random
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from conftest import hypothesis_or_skip
 
 given, settings, st = hypothesis_or_skip()
 
+from repro.core import buddy
 from repro.core import pim_malloc as pm
 from repro.core.oracle import PyPimMalloc
 
@@ -161,3 +164,120 @@ def test_api_allocator_roundtrip():
     a.pimFree(p2)
     assert a.stats["front_hits"] == 2
     assert a.stats["frees_small"] == 2
+
+
+# Rounds of a batch of cores: (sizes, active), each [K cores][T threads].
+K = 3
+_IDLE = ([[64] * 4] * K, [[False] * 4] * K)   # the fleet's all-false mask
+
+
+def _round(sizes):
+    return sizes, [[z > 0 for z in row] for row in sizes]
+
+
+def _exhausted_rounds():
+    """Each core holds 4 blocks. Threads 0-2 refill class 2048 >> k and
+    drain it to count 0 (stale rows stay), then all four refill: thread 0
+    takes the last block and threads 1-3 fail on the exhausted heap."""
+    sizes = [2048 >> k for k in range(K)]
+    drains = [CFG.block_bytes // z - 1 for z in sizes]   # hits after a refill
+    rounds = [_round([[z] * 3 + [0] for z in sizes])]
+    for d in range(max(drains)):
+        rounds.append(_round([[z] * 3 + [0] if d < n else [0] * 4
+                              for z, n in zip(sizes, drains)]))
+    return rounds + [_round([[z] * 4 for z in sizes])]
+
+
+CLASSES = PyPimMalloc().cfg["classes"]
+REFILL_CASES = {
+    # every thread of a core refills one class; the class differs by core
+    "same_class": (1 << 18, False, [_round([[16 << k] * 4 for k in range(K)])] * 2),
+    # each thread refills its own class, then others with a bypass between
+    "mixed_classes": (1 << 18, False, [
+        _round([[CLASSES[(t + k) % 8] for t in range(4)] for k in range(K)]),
+        _round([[CLASSES[(t + k + 4) % 8] for t in range(3)] + [8192]
+                for k in range(K)])]),
+    # prepopulated lists of 2048 >> k drain and refill on different rounds
+    "prepopulated": (1 << 18, True,
+                     [_round([[2048 >> k] * 4 for k in range(K)])] * 9),
+    "exhausted": (1 << 14, False, _exhausted_rounds()),
+}
+
+
+def _expected_stacks(cfg, stacks, sizes, ptrs, paths):
+    """The freelists a malloc round must leave on one core: a thread that
+    refilled (path 1) gets its new block carved into stacks[t, c, :max_sub],
+    and every other entry keeps its old value, stale ones included."""
+    out = np.array(stacks)
+    for t, (z, ptr, path) in enumerate(zip(sizes, ptrs, paths)):
+        if path == 1:
+            c = next(i for i, s in enumerate(cfg.size_classes) if z <= s)
+            csize = cfg.size_classes[c]
+            sub = cfg.block_bytes // csize
+            base = ptr - (sub - 1) * csize
+            out[t, c, :cfg.max_sub] = [base + i * csize if i < sub else -1
+                                       for i in range(cfg.max_sub)]
+    return out
+
+
+@pytest.mark.parametrize("case", sorted(REFILL_CASES))
+def test_vmapped_refills_match_oracle(case):
+    """malloc vmapped over cores, as the fleet runs it, against one oracle
+    per core: pointers, every MallocEvent field (buddy fields replayed
+    through `buddy.alloc` in backend order), the full `stacks` array and
+    the rest of the state, round by round, ending with an idle round."""
+    heap, prepopulate, rounds = REFILL_CASES[case]
+    cfg = pm.PimMallocConfig(heap_bytes=heap, num_threads=4)
+    st_ = jax.tree.map(lambda x: jnp.stack([x] * K), pm.init(cfg, prepopulate))
+    pys = [PyPimMalloc(heap_bytes=heap, num_threads=4, prepopulate=prepopulate)
+           for _ in range(K)]
+    step = jax.jit(jax.vmap(lambda s, z, a: pm.malloc(cfg, s, z, a)))
+    balloc = jax.jit(functools.partial(buddy.alloc, cfg.buddy_cfg))
+    tlen = cfg.buddy_cfg.trace_len
+    seen = set()
+    for r, (sizes, active) in enumerate(rounds + [_IDLE]):
+        prev = jax.tree.map(np.asarray, st_)
+        st_, ptrs, ev = step(st_, jnp.array(sizes, jnp.int32),
+                             jnp.array(active))
+        for k, py in enumerate(pys):
+            where = (case, r, k)
+            pptrs, ppaths = py.malloc(sizes[k], active[k])
+            assert np.asarray(ptrs[k]).tolist() == pptrs, where
+            assert np.asarray(ev.path[k]).tolist() == ppaths, where
+            # the backend in thread order, from the round's first state
+            bst = buddy.BuddyState(longest=jnp.asarray(prev.buddy.longest[k]))
+            want = {"backend_pos": [-1] * 4, "levels_down": [0] * 4,
+                    "levels_up": [0] * 4, "trace": [[-1] * tlen] * 4}
+            for pos, t in enumerate(t for t in range(4) if ppaths[t] > 0):
+                z = sizes[k][t]
+                bsize = (cfg.block_bytes if z <= cfg.max_class
+                         else max(1 << (z - 1).bit_length(), cfg.block_bytes))
+                bst, _, bev = balloc(bst, jnp.int32(bsize))
+                want["backend_pos"][t] = pos
+                want["levels_down"][t] = int(bev.levels_down)
+                want["levels_up"][t] = int(bev.levels_up)
+                want["trace"][t] = np.asarray(bev.trace).tolist()
+            for name, value in want.items():
+                assert np.asarray(getattr(ev, name)[k]).tolist() == value, (
+                    where, name)
+            # state
+            got = jax.tree.map(lambda x: np.asarray(x[k]), st_)
+            np.testing.assert_array_equal(
+                got.stacks, _expected_stacks(cfg, prev.stacks[k], sizes[k],
+                                             pptrs, ppaths), err_msg=str(where))
+            assert got.buddy.longest.tolist() == py.buddy.longest, where
+            assert got.counts.tolist() == py.counts, where
+            for t in range(4):
+                for c in range(cfg.nc):
+                    n = py.counts[t][c]
+                    assert got.stacks[t, c, :n].tolist() == py.stacks[t][c], where
+            nb = range(cfg.nb)
+            assert got.block_cls.tolist() == [py.block_cls.get(b, -1) for b in nb]
+            assert got.block_free.tolist() == [py.block_free.get(b, 0) for b in nb]
+            assert got.big_log2.tolist() == [py.big_log2.get(b, -1) for b in nb]
+            for name in ("front_hits", "front_misses", "bypass", "fails"):
+                assert int(getattr(got.stats, name)) == py.stats[name], (
+                    where, name)
+            seen.update(ppaths)
+    # refills committed on every case, and refills failed where the heap runs out
+    assert 1 in seen and (3 in seen) == (case == "exhausted"), seen

@@ -7,6 +7,8 @@ ranks x 64 cores x 16 tasklets, 32 MiB heaps) for one chip of a ``v5e:2x2``
 and rank-sharded over all four. The topology is described inside a fixture,
 so only the worker that runs this file loads the TPU compiler.
 """
+import re
+
 import numpy as np
 import pytest
 
@@ -16,6 +18,7 @@ from jax.sharding import (AxisType, Mesh, NamedSharding, PartitionSpec,
                           SingleDeviceSharding)
 
 from repro.core import heap as heap_api
+from repro.core import pim_malloc as pm
 from repro.core import system as sysm
 from repro.launch.serve_fleet import FleetServe
 
@@ -84,3 +87,30 @@ def test_rank_sharded_fleet_scan_compiles_on_2x2(topo):
     # each chip holds its two ranks of the state, not the whole fleet
     assert ma.argument_size_in_bytes < state_bytes / 3
     assert _device_bytes(ma) < V5E_HBM_BYTES
+
+
+def test_malloc_refill_is_not_a_per_core_loop(topo):
+    """Under the fleet's two vmaps, a freelist-row write at a per-core class
+    index is a batched gather and scatter, which the TPU backend expands
+    into serial `while` loops over the cores. malloc writes its refilled
+    rows in one dense select after the backend scan, so no such loop may
+    carry `stacks` in the compiled program."""
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    cfg = pm.PimMallocConfig(heap_bytes=1 << 20, num_threads=T)
+    ranks, cores = 2, 8
+    state = jax.eval_shape(jax.vmap(jax.vmap(lambda _: pm.init(cfg))),
+                           jnp.zeros((ranks, cores)))
+    state = jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+        s.shape, s.dtype, sharding=one_chip), state)
+    sizes = jax.ShapeDtypeStruct((ranks, cores, T), jnp.int32,
+                                 sharding=one_chip)
+    hlo = jax.jit(jax.vmap(jax.vmap(lambda s, z: pm.malloc(cfg, s, z)))).lower(
+        state, sizes).compile().as_text()
+    stacks = f"s32[{ranks},{cores},{T},{cfg.nc},{cfg.cap}]"
+    whiles = [line for line in hlo.splitlines()
+              if re.search(r"= .*\bwhile\(", line)]
+    assert whiles, "no while loop found: the HLO text format changed"
+    row_loops = [line.split(" = ")[0].strip() for line in whiles
+                 if stacks in line
+                 and re.search(r'op_name="[^"]*/(scatter|gather)"', line)]
+    assert not row_loops, row_loops
