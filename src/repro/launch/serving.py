@@ -101,8 +101,19 @@ def pct(x, percentiles=PERCENTILES) -> dict:
 
 
 def response_host(resps: AllocResponse) -> dict:
-    """One device->host conversion per response field, reused throughout."""
-    return {f: np.asarray(getattr(resps, f)) for f in AllocResponse._fields}
+    """One device->host conversion per response field, reused throughout.
+    Span: `serve/readback`, counting `moved` (relocated reallocs)."""
+    with span("serve/readback") as counts:
+        host = {f: np.asarray(getattr(resps, f))
+                for f in AllocResponse._fields}
+        if counts is not None:
+            counts["moved"] = int(np.count_nonzero(host["moved"]))
+    return host
+
+
+def _reallocs(op) -> int:
+    """REALLOC entries of a plan's op grid."""
+    return int(np.count_nonzero(np.asarray(op) == OP_REALLOC))
 
 
 def round_barrier_cum(lat: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
@@ -245,10 +256,12 @@ class ScanEngine:
         """Execute rounds [r0, r1) of a planned session (r1 = r0 + segment
         length implied by the sliced grids passed via ``plan`` tuple
         ``(op, size, ptr_ref, ptr_raw)``); returns (state, slots, resps).
-        Spans: `serve/segment` around `serve/h2d` and `serve/dispatch`, as
-        in :meth:`run`."""
+        Spans: `serve/segment` (counts `rounds`, `h2d_bytes`, `reallocs`)
+        around `serve/h2d` and `serve/dispatch`, as in :meth:`run`."""
         with span("serve/segment", rounds=int(plan[0].shape[0]),
-                  h2d_bytes=sum(int(g.nbytes) for g in plan)):
+                  h2d_bytes=sum(int(g.nbytes) for g in plan)) as counts:
+            if counts is not None:
+                counts["reallocs"] = _reallocs(plan[0])
             with span("serve/h2d"):
                 grids = [jnp.asarray(g) for g in plan]
             with span("serve/dispatch"):
@@ -258,13 +271,16 @@ class ScanEngine:
         """Execute a planned session on a fresh fleet; returns the final
         sharded state and the stacked [rounds, R, C, T] responses.
 
-        Spans (`repro.runtime.spans`): `serve/session` (counts `rounds` and
-        `h2d_bytes`, the plan grids' bytes) around `serve/init` (state
-        init), `serve/h2d` (the grids' copies) and `serve/dispatch` (the
-        scan's dispatch; its device work ends after the call returns)."""
+        Spans (`repro.runtime.spans`): `serve/session` (counts `rounds`,
+        `h2d_bytes`, the plan grids' bytes, and `reallocs`, their REALLOC
+        entries) around `serve/init` (state init), `serve/h2d` (the grids'
+        copies) and `serve/dispatch` (the scan's dispatch; its device work
+        ends after the call returns)."""
         grids = (plan.op, plan.size, plan.ptr_ref, plan.ptr_raw)
         with span("serve/session", rounds=int(plan.op.shape[0]),
-                  h2d_bytes=sum(int(g.nbytes) for g in grids)):
+                  h2d_bytes=sum(int(g.nbytes) for g in grids)) as counts:
+            if counts is not None:
+                counts["reallocs"] = _reallocs(plan.op)
             with span("serve/init"):
                 state = heap_api.sharded_init(self.cfg, self.num_ranks,
                                               self.num_cores)

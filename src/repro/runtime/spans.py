@@ -18,7 +18,8 @@ around it, so every span of one served session shares it. The log holds the
 latest capture only: the first span that opens during a capture, after one
 that opened with none running, empties it. With no capture running a span
 costs one annotation and one `is_enabled()` check, a few microseconds of
-host time; the serving path opens four a session.
+host time; the serving path opens four a session, and one more where its
+answers are read back (`serving.response_host`).
 """
 from __future__ import annotations
 
@@ -58,13 +59,16 @@ _was_enabled = False
 
 @contextlib.contextmanager
 def span(name: str, **counts):
-    """A host span named `name`; `counts` are recorded with it."""
+    """A host span named `name`; `counts` are recorded with it. While a
+    capture runs it yields the counts dict, so a count known only inside
+    the span can be added to it; with none running it yields None, so a
+    count that costs work is computed only where it is recorded."""
     global _was_enabled
     with jax.profiler.TraceAnnotation(name):
         enabled = jax.profiler.TraceAnnotation.is_enabled()
         if not enabled:
             _was_enabled = False
-            yield
+            yield None
             return
         with _lock:
             if not _was_enabled:
@@ -76,7 +80,7 @@ def span(name: str, **counts):
         stack.append((sid, session))
         start = time.perf_counter()
         try:
-            yield
+            yield counts
         finally:
             end = time.perf_counter()
             stack.pop()

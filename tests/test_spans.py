@@ -83,6 +83,19 @@ def test_no_capture_records_nothing(engine):
     assert spans.count("serve/session", "rounds") == 0
 
 
+def test_no_capture_computes_no_count(engine, monkeypatch):
+    from repro.launch import serving
+
+    def counted(op):
+        raise AssertionError("a count computed with no capture running")
+
+    monkeypatch.setattr(serving, "_reallocs", counted)
+    serving.response_host(_serve(engine, _plan(engine)))
+    with spans.span("serve/readback") as counts:
+        assert counts is None
+    assert spans.records() == []
+
+
 def test_session_spans_under_a_capture(engine, tmp_path):
     plan = _plan(engine)
     _captured(engine, plan, tmp_path)
@@ -92,7 +105,8 @@ def test_session_spans_under_a_capture(engine, tmp_path):
     session = recs[0]
     assert session.parent is None
     assert session.counts == {"rounds": ROUNDS,
-                              "h2d_bytes": 4 * plan.op.nbytes}
+                              "h2d_bytes": 4 * plan.op.nbytes,
+                              "reallocs": 0}
     for child in recs[1:]:
         assert child.parent == session.id
         assert child.session == session.session == session.id
@@ -141,7 +155,8 @@ def test_segment_spans(engine, tmp_path):
     assert [r.name for r in recs] == ["serve/segment", "serve/h2d",
                                       "serve/dispatch"]
     assert recs[0].counts == {"rounds": 2,
-                              "h2d_bytes": 4 * plan.op[:2].nbytes}
+                              "h2d_bytes": 4 * plan.op[:2].nbytes,
+                              "reallocs": 0}
     assert {r.session for r in recs} == {recs[0].id}
 
 
