@@ -72,10 +72,12 @@ def sw_buffer_access(cfg: SWBufferConfig, st: SWBufferState, node):
     valid = node >= 0
     word = jnp.maximum(node, 0) // NODES_PER_WORD
     line = word // cfg.line_words
-    idx = line % cfg.n_lines
-    hit = valid & (st.tags[idx] == line)
+    # one-hot select over the lines, not an indexed read and write: under
+    # the fleet's vmaps a per-core index is a gather and a scatter per access
+    sel = lax.iota(jnp.int32, cfg.n_lines) == line % cfg.n_lines
+    hit = valid & jnp.any(sel & (st.tags == line))
     miss = valid & ~hit
-    tags = st.tags.at[idx].set(jnp.where(miss, line, st.tags[idx]))
+    tags = jnp.where(sel & miss, line, st.tags)
     dram = jnp.where(miss, cfg.line_bytes, 0).astype(jnp.int32)
     return SWBufferState(tags=tags), hit, dram
 
@@ -110,8 +112,10 @@ def buddy_cache_access(cfg: BuddyCacheConfig, st: BuddyCacheState, node):
     victim = jnp.argmin(st.last_used)  # invalid entries (-1) chosen first
     idx = jnp.where(hit, hit_idx, victim)
     do = valid
-    tags = st.tags.at[idx].set(jnp.where(do, word, st.tags[idx]))
-    last = st.last_used.at[idx].set(jnp.where(do, st.clock, st.last_used[idx]))
+    # one-hot select over the entries (see sw_buffer_access)
+    sel = (lax.iota(jnp.int32, st.tags.shape[0]) == idx) & do
+    tags = jnp.where(sel, word, st.tags)
+    last = jnp.where(sel, st.clock, st.last_used)
     clock = st.clock + do.astype(jnp.int32)
     dram = jnp.where(valid & ~hit, WORD_BYTES, 0).astype(jnp.int32)
     return BuddyCacheState(tags=tags, last_used=last, clock=clock), hit, dram

@@ -6,6 +6,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import lax
 
 from repro.core import buddy_cache as bc
 from repro.core import cost_model as cm
@@ -78,6 +79,126 @@ def test_invalid_nodes_skipped():
         functools.partial(bc.buddy_cache_access, cfg), st, traces
     )
     assert int(stats.hits[0]) == 0 and int(stats.misses[0]) == 1
+
+
+# The indexed formulations the access functions had before they became dense
+# one-hot selects: the reference the dense forms must match bit for bit.
+def _indexed_sw_buffer_access(cfg, st, node):
+    valid = node >= 0
+    word = jnp.maximum(node, 0) // bc.NODES_PER_WORD
+    line = word // cfg.line_words
+    idx = line % cfg.n_lines
+    hit = valid & (st.tags[idx] == line)
+    miss = valid & ~hit
+    tags = st.tags.at[idx].set(jnp.where(miss, line, st.tags[idx]))
+    dram = jnp.where(miss, cfg.line_bytes, 0).astype(jnp.int32)
+    return bc.SWBufferState(tags=tags), hit, dram
+
+
+def _indexed_buddy_cache_access(cfg, st, node):
+    del cfg
+    valid = node >= 0
+    word = jnp.maximum(node, 0) // bc.NODES_PER_WORD
+    match = st.tags == word
+    hit = valid & jnp.any(match)
+    idx = jnp.where(hit, jnp.argmax(match), jnp.argmin(st.last_used))
+    tags = st.tags.at[idx].set(jnp.where(valid, word, st.tags[idx]))
+    last = st.last_used.at[idx].set(
+        jnp.where(valid, st.clock, st.last_used[idx]))
+    clock = st.clock + valid.astype(jnp.int32)
+    dram = jnp.where(valid & ~hit, bc.WORD_BYTES, 0).astype(jnp.int32)
+    return bc.BuddyCacheState(tags=tags, last_used=last, clock=clock), hit, dram
+
+
+# name -> (config, init, dense access, indexed access, distinct words a
+# full cache holds, nodes per slot)
+_CACHES = {
+    "sw": (bc.SWBufferConfig(), bc.sw_buffer_init, bc.sw_buffer_access,
+           _indexed_sw_buffer_access, bc.SWBufferConfig().n_lines,
+           bc.SWBufferConfig().line_words * bc.NODES_PER_WORD),
+    "hwsw2": (bc.BuddyCacheConfig(n_entries=2), bc.buddy_cache_init,
+              bc.buddy_cache_access, _indexed_buddy_cache_access, 2,
+              bc.NODES_PER_WORD),
+    "hwsw16": (bc.BuddyCacheConfig(n_entries=16), bc.buddy_cache_init,
+               bc.buddy_cache_access, _indexed_buddy_cache_access, 16,
+               bc.NODES_PER_WORD),
+}
+
+
+def _node_stream(rng, shape, slots, per_slot):
+    """Nodes over ~3x as many words (or lines) as the cache holds, so words
+    repeat and evict each other, with a quarter of them invalid (-1)."""
+    nodes = rng.integers(0, 3 * slots * per_slot, size=shape)
+    return np.where(rng.random(shape) < 0.25, -1, nodes).astype(np.int32)
+
+
+def _assert_trees_equal(a, b):
+    for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b), strict=True):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+
+@pytest.mark.parametrize("start", ["empty", "full"])
+@pytest.mark.parametrize("cache", sorted(_CACHES))
+def test_dense_access_matches_indexed(cache, start):
+    """The one-hot access functions give the indexed formulation's state,
+    hit and DRAM bytes after every access of a random stream."""
+    cfg, init, dense, indexed, slots, per_slot = _CACHES[cache]
+    rng = np.random.default_rng(sum(map(ord, cache + start)))
+    st = init(cfg)
+    if start == "full":  # one distinct word (or line) in every slot
+        fill = jnp.arange(slots, dtype=jnp.int32) * per_slot
+        st, _ = lax.scan(lambda s, n: (indexed(cfg, s, n)[0], None), st, fill)
+        assert int(jnp.min(st.tags)) >= 0
+    nodes = jnp.asarray(_node_stream(rng, (400,), slots, per_slot))
+
+    def run(access):
+        return jax.jit(lambda s, ns: lax.scan(
+            lambda c, n: (lambda o: (o[0], o[1:]))(access(cfg, c, n)),
+            s, ns))(st, nodes)
+
+    _assert_trees_equal(run(dense), run(indexed))
+
+
+@pytest.mark.parametrize("kind", ["sw", "hwsw"])
+def test_dense_cache_pass_matches_indexed_on_a_fleet(kind, monkeypatch):
+    """`simulate_traces` and `system._cache_pass` under the fleet's two
+    vmaps: the dense access functions give the indexed ones' final state
+    and per-op hits, misses and DRAM bytes, over two rounds (the second
+    starts from the first's warm, full caches)."""
+    R, C, T = 2, 3, 4
+    cfg = sysm.SystemConfig(kind=kind, heap_bytes=1 << 18, num_threads=T)
+    L = cfg.trace_len
+    rng = np.random.default_rng(7)
+    slots = (cfg.bc.n_entries if kind == "hwsw" else cfg.sw_buf.n_lines)
+    per_slot = bc.NODES_PER_WORD * (1 if kind == "hwsw"
+                                    else cfg.sw_buf.line_words)
+    rounds = []
+    for _ in range(2):
+        # a random subset of the 2T ops reaches the backend, in random order
+        backend = rng.random((R, C, 2 * T)) < 0.6
+        bpos = np.where(backend, np.argsort(rng.random((R, C, 2 * T)), -1), -1)
+        traces = _node_stream(rng, (R, C, 2 * T, L), slots, per_slot)
+        traces = np.where(backend[..., None], traces, -1)
+        rounds.append((jnp.asarray(bpos, jnp.int32), jnp.asarray(traces)))
+    cache0 = jax.vmap(jax.vmap(lambda _: cfg.cache_init()))(jnp.zeros((R, C)))
+
+    def run():
+        fleet = jax.jit(jax.vmap(jax.vmap(
+            lambda c, b, t: sysm._cache_pass(cfg, c, b, t))))
+        sim = jax.jit(jax.vmap(jax.vmap(
+            lambda c, t: bc.simulate_traces(cfg.access_fn, c, t))))
+        out, cache = [], cache0
+        for bpos, traces in rounds:
+            cache, stats = fleet(cache, bpos, traces)
+            out += [cache, stats, sim(cache0, traces)]
+        return out
+
+    dense = run()
+    assert int(jnp.min(dense[0].tags)) >= 0, "round 1 left a cache not full"
+    monkeypatch.setattr(sysm, "sw_buffer_access", _indexed_sw_buffer_access)
+    monkeypatch.setattr(sysm, "buddy_cache_access",
+                        _indexed_buddy_cache_access)
+    _assert_trees_equal(dense, run())
 
 
 # ------------------------------------------------------------------ cost model

@@ -114,3 +114,70 @@ def test_malloc_refill_is_not_a_per_core_loop(topo):
                  if stacks in line
                  and re.search(r'op_name="[^"]*/(scatter|gather)"', line)]
     assert not row_loops, row_loops
+
+
+def _computations(hlo: str) -> dict[str, list[str]]:
+    """HLO text -> {computation name: its instruction lines}."""
+    comps, name = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"(?:ENTRY )?%(\S+) \(.*\{$", line)
+        if head:
+            name = head.group(1)
+            comps[name] = []
+        elif line == "}":
+            name = None
+        elif name is not None:
+            comps[name].append(line)
+    return comps
+
+
+def _reachable(comps: dict[str, list[str]], root: str) -> set[str]:
+    """`root` and every computation it calls, transitively (loop bodies,
+    fusions, reducers, branches)."""
+    seen, todo = set(), [root]
+    while todo:
+        name = todo.pop()
+        if name not in seen:
+            seen.add(name)
+            todo += [n for line in comps[name]
+                     for n in re.findall(r"%([\w.\-]+)", line) if n in comps]
+    return seen
+
+
+@pytest.mark.parametrize("kind", ["sw", "hwsw"])
+def test_cache_pass_is_not_a_per_core_gather(topo, kind):
+    """Under the fleet's two vmaps, a cache-state read or write at a
+    per-core entry index is a batched gather or scatter. Inside the cache
+    pass's inner loop (one access per step, 2T x trace_len steps a round)
+    each one costs tens of microseconds on a v5e, so the access functions
+    select their entry with a dense one-hot mask instead. No gather or
+    scatter may sit in the inner `simulate_traces` loop body."""
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    cfg = sysm.SystemConfig(kind=kind, heap_bytes=1 << 20, num_threads=T)
+    ranks, cores = 2, 8
+
+    def spec(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    cache = jax.eval_shape(jax.vmap(jax.vmap(lambda _: cfg.cache_init())),
+                           jnp.zeros((ranks, cores)))
+    cache = jax.tree.map(lambda s: spec(s.shape, s.dtype), cache)
+    bpos = spec((ranks, cores, 2 * T))
+    traces = spec((ranks, cores, 2 * T, cfg.trace_len))
+    hlo = jax.jit(jax.vmap(jax.vmap(
+        lambda c, b, t: sysm._cache_pass(cfg, c, b, t)))).lower(
+            cache, bpos, traces).compile().as_text()
+    comps = _computations(hlo)
+    loops = [(name, re.search(r"body=%([\w.\-]+)", line).group(1))
+             for name, lines in comps.items() for line in lines
+             if re.search(r"= .*\bwhile\(", line)]
+    assert len(loops) >= 2, "no nested while loops found: the HLO text " \
+                            "format or the loop structure changed"
+    outer = set().union(*(_reachable(comps, body) for _, body in loops))
+    inner = [body for name, body in loops if name in outer]
+    assert inner, "no while loop nested in another: the HLO text format " \
+                  "or the loop structure changed"
+    hits = [f"{comp}: {line.strip()[:120]}"
+            for body in inner for comp in _reachable(comps, body)
+            for line in comps[comp] if re.search(r"\b(gather|scatter)\(", line)]
+    assert not hits, hits
