@@ -12,9 +12,6 @@ returns the record, so modules stay single-sourced.
 from __future__ import annotations
 
 import numbers
-import platform
-import sys
-import time
 
 import jax
 import jax.numpy as jnp
@@ -34,7 +31,7 @@ def emit(name: str, us_per_call: float, derived: str = "",
     metadata_bytes_per_op, ...) for the JSON artifact. Every record is
     stamped with the jax version, and — when the row measures a specific
     allocator design point — with its ``backend`` name
-    (strawman/sw/hwsw/pallas), so baseline diffs stay attributable across
+    (strawman/sw/hwsw/...), so baseline diffs stay attributable across
     environments and backend axes.
     """
     row = f"{name},{us_per_call:.4f},{derived}"
@@ -91,69 +88,4 @@ def micro_alloc(kind: str, size: int, nthreads: int, rounds: int = 128,
         "ops": ops,
         "allocs_per_sec": ops / max(modeled_s, 1e-12),
         "metadata_bytes_per_op": dram / max(ops, 1),
-    }
-
-
-def wall_env_key() -> str:
-    """Coarse runner class stamped on wall-clock rows.
-
-    Wall numbers are only comparable between runs on the same OS / arch /
-    jax backend / execution mode (CPU-interpret vs compiled device) — the
-    perf gate refuses to diff wall rows across different env keys, so a
-    TPU baseline can never gate a CPU CI runner or vice versa. Machine
-    *speed* within a class still varies; that's what the generous
-    ``--fail-over-wall`` threshold absorbs.
-    """
-    from repro.kernels.ops import on_tpu
-    mode = "compiled" if on_tpu() else "interpret"
-    return f"{sys.platform}-{platform.machine()}-{jax.default_backend()}-{mode}"
-
-
-def timed(fn, *args, warmup: int = 1, repeats: int = 5):
-    """Median wall seconds of ``fn(*args)``, fully materialized.
-
-    Compiles/warms with ``warmup`` untimed calls, then times ``repeats``
-    calls under `jax.block_until_ready` and returns
-    ``(median_seconds, last_output)``.
-    """
-    out = None
-    for _ in range(max(warmup, 1)):
-        out = jax.block_until_ready(fn(*args))
-    samples = []
-    for _ in range(max(repeats, 1)):
-        t0 = time.perf_counter()
-        out = jax.block_until_ready(fn(*args))
-        samples.append(time.perf_counter() - t0)
-    return float(np.median(samples)), out
-
-
-def micro_alloc_wall(kind: str, size: int, nthreads: int, rounds: int = 96,
-                     heap: int = 1 << 25, T: int = 16, warmup: int = 1,
-                     repeats: int = 5, batch_refill: bool = None):
-    """Wall-clock companion of `micro_alloc`: measured execution time of the
-    same compiled round loop, plus modeled stats from the executed responses
-    so every wall row carries its modeled counterpart for delta reporting.
-
-    ``batch_refill`` only affects the ``pallas`` kind (None = env default);
-    passing False measures the pre-batching serial kernel for the committed
-    speedup row.
-    """
-    cfg = sysm.SystemConfig(kind=kind, heap_bytes=heap, num_threads=T,
-                            kernel_batch_refill=batch_refill)
-    st = heap_api.init(cfg)
-    sizes = jnp.where(jnp.arange(T) < nthreads, size, 0).astype(jnp.int32)
-    sz = jnp.tile(sizes[None, :], (rounds, 1))
-    run = jax.jit(lambda s, z: heap_api.run_rounds(
-        cfg, s, jax.vmap(heap_api.malloc_request)(z)))
-    wall_s, (_, resp) = timed(run, st, sz, warmup=warmup, repeats=repeats)
-    lat = np.asarray(resp.latency_cyc)[:, :nthreads]
-    modeled_s = float(lat.max(axis=1).sum()) / cfg.dpu.freq_hz
-    ops = rounds * nthreads
-    return {
-        "wall_us_per_round": wall_s / rounds * 1e6,
-        "wall_us_per_call": wall_s / ops * 1e6,
-        "modeled_us_per_call": modeled_s / ops * 1e6,
-        "rounds_per_sec": rounds / max(wall_s, 1e-12),
-        "ops": ops,
-        "rounds": rounds,
     }

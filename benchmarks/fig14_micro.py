@@ -19,7 +19,7 @@ def bench(smoke: bool = False):
     res = {}
     for nt in (1, 16):
         for size in (32, 256, 4096):
-            for kind in ("strawman", "sw", "hwsw", "pallas"):
+            for kind in ("strawman", "sw", "hwsw"):
                 r = micro_alloc(kind, size, nthreads=nt, rounds=rounds)
                 res[(kind, size, nt)] = r["mean_us"]
                 recs.append(emit(
@@ -53,17 +53,6 @@ def bench(smoke: bool = False):
             f"fig14/small_size_speedup/threads={nt}", res[("sw", 32, nt)],
             f"{r32:.0f}x at 32B (brackets the paper's 66x from above)",
             speedup_32b=r32))
-    # fused-kernel design point: modeled latency must track hwsw 1:1 (the
-    # kernel is bitwise-conformant; this row guards the claim in the bench
-    # trajectory, CI fails the ERROR row if parity drifts)
-    par = np.mean([res[("pallas", z, nt)] / res[("hwsw", z, nt)]
-                   for z in (32, 256, 4096) for nt in (1, 16)])
-    if not 0.999 <= par <= 1.001:
-        raise AssertionError(f"pallas/hwsw modeled-latency parity broke: {par}")
-    recs.append(emit(
-        "fig14/pallas_parity_vs_hwsw", res[("pallas", 256, 16)],
-        f"mean_ratio={par:.4f} (fused kernel == hwsw model)",
-        backend="pallas", parity_ratio=par))
     return recs
 
 

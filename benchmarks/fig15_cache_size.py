@@ -11,8 +11,7 @@ from .common import emit, micro_alloc
 
 
 def _cache_cell(kind, cache_bytes, rounds):
-    """One (kind, cache size) cell; the hwsw sim path and the fused-kernel
-    path share this loop, so the sweep exercises both designs."""
+    """One (kind, cache size) cell of the buddy-cache sweep."""
     cfg = sysm.SystemConfig(
         kind=kind, heap_bytes=1 << 25,
         bc=buddy_cache.BuddyCacheConfig(n_entries=cache_bytes // 4))
@@ -41,21 +40,6 @@ def bench(smoke: bool = False):
             f"speedup_vs_sw={sw['mean_us'] / us:.2f}x;hit_rate={hr:.2f}",
             backend="hwsw", hit_rate=hr, speedup_vs_sw=sw["mean_us"] / us,
             metadata_bytes_per_op=meta))
-        # same sweep (same rounds) on the kernel path: the in-kernel LRU is
-        # bitwise-conformant in interpret mode (exactly equal cells); on a
-        # TPU the compiled kernel may differ by float ulps, so guard with
-        # the same tolerance band fig14's parity row uses
-        us_k, hr_k, meta_k = _cache_cell("pallas", cache_bytes, rounds)
-        close = all(abs(a - b) <= 1e-3 * max(abs(b), 1e-9)
-                    for a, b in ((us_k, us), (hr_k, hr), (meta_k, meta)))
-        if not close:
-            raise AssertionError(
-                f"pallas/hwsw fig15 cell diverged at {cache_bytes}B: "
-                f"{(us_k, hr_k, meta_k)} != {(us, hr, meta)}")
-        recs.append(emit(
-            f"fig15/pallas/cache={cache_bytes}B", us_k,
-            f"hit_rate={hr_k:.2f} (in-kernel LRU == hwsw sim)",
-            backend="pallas", hit_rate=hr_k, metadata_bytes_per_op=meta_k))
     recs.append(emit(
         "fig15/claim", 0.0,
         "paper: speedup and hit rate saturate at 64B (=256 nodes at 2b)"))

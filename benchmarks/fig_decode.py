@@ -3,7 +3,7 @@
 Runs `repro.launch.serve_decode.DecodeServe` sessions — Poisson session
 arrivals, Zipf tenant popularity, prefill bursts, per-token page appends,
 eviction — on the shard_mapped rank mesh (``mesh=None``), for the hwsw
-reference backend and the fused pallas kernel. One row per backend:
+backend. One row per backend:
 
   * ``us_per_call``     — modeled us per dispatched allocator op (the
                           perf-gated trajectory number)
@@ -25,7 +25,7 @@ from repro.launch.serve_decode import DecodeTraffic, serve_decode_session
 
 from .common import emit
 
-KINDS = ("hwsw", "pallas")
+KINDS = ("hwsw",)
 
 
 def bench(smoke: bool = False):

@@ -135,17 +135,6 @@ def bench(smoke: bool = False):
         f"strawman_vs_sw={slow:.1f}x (shared mutex vs per-thread caches)",
         backend="strawman", slowdown_vs_sw=slow))
 
-    # fused-kernel backend at fleet scale: the same router/mesh path with
-    # heap.step served by one pallas_call per core (vmap -> kernel grid)
-    Rk, Ck = (ranks_list[0], cores_list[-1])
-    rk = _cell("pallas", Rk, Ck, T, max(rounds // 3, 2))
-    recs.append(emit(
-        f"fig_fleet/pallas/ranks={Rk}/cores={Ck}", rk["us_per_call"],
-        f"allocs_per_sec={rk['allocs_per_sec']:.0f};"
-        f"wall_step={rk['wall_us_per_step']:.0f}us", backend="pallas",
-        allocs_per_sec=rk["allocs_per_sec"],
-        metadata_bytes_per_op=rk["metadata_bytes_per_op"],
-        wall_us_per_step=rk["wall_us_per_step"]))
     return recs
 
 

@@ -26,21 +26,19 @@ import time
 
 from repro.runtime.compile_cache import enable_compile_cache
 
-ALL = ("fig5", "fig6", "fig7", "fig14", "fig14_wall", "fig15", "fig16",
+ALL = ("fig5", "fig6", "fig7", "fig14", "fig15", "fig16",
        "fig_fleet", "fleet_serve", "fig_decode", "workloads", "fig_arena",
        "fig_elastic", "roofline")
 SCHEMA = "pim-malloc-bench/v1"
 # per-record attribution stamps (the only non-numeric record fields besides
-# name/derived): allocator design point, jax version, and for wall-clock
-# rows the row family marker + runner class (see common.wall_env_key)
-STRING_FIELDS = ("backend", "jax", "lane", "env_key")
+# name/derived): allocator design point and jax version
+STRING_FIELDS = ("backend", "jax")
 
 _MODULES = {
     "fig5": "fig5_design_space",
     "fig6": "fig6_heap_sweep",
     "fig7": "fig7_contention",
     "fig14": "fig14_micro",
-    "fig14_wall": "fig14_wall",
     "fig15": "fig15_cache_size",
     "fig16": "fig16_graph",
     "fig_fleet": "fig_fleet",

@@ -10,9 +10,7 @@ donated `lax.scan` on the device, then the host report. It checks that no
 free was dropped, that every core's heap conserves its bytes, that sampled
 (rank, core) tapes agree op for op with the plain-Python `PyPimMalloc`
 oracle, and that the final state lies on the TPU. Then one shorter session
-runs per other XLA kind at the same geometry. `pallas` is listed, not run:
-its kernel does not compile for TPU yet, and the check here is that it
-refuses to run the interpreter on the chip instead. Every session is served
+runs per other kind at the same geometry. Every session is served
 twice: the second serve times the compiled scan alone and must answer as
 the first did.
 
@@ -40,11 +38,9 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
                                 "src"))
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.paper_upmem import CONFIG as PAPER
-from repro.core import heap as heap_api
 from repro.core import system as sysm
 from repro.core.heap import OP_NOOP, OP_REALLOC
 from repro.core.oracle import PyPimMalloc
@@ -232,27 +228,6 @@ def kind_phase(cfg, R: int, C: int, traffic: TrafficConfig, dev) -> dict:
     return session(tag, cfg, R, C, traffic, dev)[3]
 
 
-def check_pallas_refuses_interpret() -> str:
-    """`fused_heap_step(interpret=True)` must raise on a TPU backend rather
-    than run the Pallas interpreter there. Returns the refusal message."""
-    from repro.kernels import heap_step
-    cfg = sysm.SystemConfig(kind="pallas", heap_bytes=1 << 16, num_threads=4)
-    st = heap_api.init(cfg)
-    al, ca = st.alloc, st.cache
-    z = jnp.zeros((cfg.num_threads,), jnp.int32)
-    try:
-        heap_step.fused_heap_step(
-            z, z, z - 1, al.buddy.longest, al.counts, al.stacks,
-            al.block_cls, al.block_free, al.big_log2, ca.tags, ca.last_used,
-            jnp.reshape(ca.clock, (1,)), heap_bytes=cfg.pm.heap_bytes,
-            block_bytes=cfg.pm.block_bytes,
-            size_classes=cfg.pm.size_classes, interpret=True)
-    except NotImplementedError as e:
-        return str(e)
-    raise SmokeFailure("chip_smoke FAILED: pallas: the Pallas interpreter "
-                       "ran on the TPU backend")
-
-
 def device_shares(state) -> dict:
     """{device: bytes of the state held there}."""
     shares = {}
@@ -341,9 +316,6 @@ def main(argv=None):
         for kind in OTHER_KINDS:
             kind_phase(sysm.SystemConfig(kind=kind, **table3), RANKS, CORES,
                        short, dev)
-        print(f"kind pallas: not compiled for TPU (ROADMAP A2); "
-              f"interpret refused: {check_pallas_refuses_interpret()}",
-              flush=True)
 
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,

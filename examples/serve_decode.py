@@ -36,8 +36,7 @@ def main():
     ap.add_argument("--placement", default="least_loaded",
                     choices=("chunked", "round_robin", "least_loaded"))
     ap.add_argument("--kind", default="sw",
-                    choices=("strawman", "sw", "hwsw", "sanitizer",
-                             "pallas"))
+                    choices=("strawman", "sw", "hwsw", "sanitizer"))
     ap.add_argument("--mesh", action="store_true",
                     help="shard_map over the rank mesh (default pure vmap)")
     ap.add_argument("--seed", type=int, default=0)

@@ -38,7 +38,7 @@ def main():
     ap.add_argument("--placement", default="round_robin",
                     choices=("chunked", "round_robin", "least_loaded"))
     ap.add_argument("--kind", default="sw",
-                    choices=("strawman", "sw", "hwsw", "pallas"))
+                    choices=("strawman", "sw", "hwsw"))
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--queue-cap", type=int, default=64)
     ap.add_argument("--tenants", type=int, default=16)

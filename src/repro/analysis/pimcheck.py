@@ -11,7 +11,7 @@ CLI (the CI `analysis` lane):
 
     python -m repro.analysis.pimcheck --all-kinds --tapes
     python -m repro.analysis.pimcheck --fixtures
-    python -m repro.analysis.pimcheck --kinds hwsw,pallas --tiers single
+    python -m repro.analysis.pimcheck --kinds sw,hwsw --tiers single
 
 Exit code is non-zero on any unsuppressed finding, tape-lint error, or
 fixture the passes fail to flag. Findings are printed per target and,

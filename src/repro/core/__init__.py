@@ -6,8 +6,9 @@ Layers (bottom-up):
                realloc/calloc)
   buddy_cache  metadata-cache simulators (SW buffer vs HW CAM+LRU)
   cost_model   DPU cycle model (UPMEM timing)
-  system       composed design points: strawman / sw / hwsw / pallas — each
-               registers a cost-instrumented `heap.step` backend
+  system       composed design points: strawman / sw / hwsw / sanitizer /
+               arena / tlregion — each registers a cost-instrumented
+               `heap.step` backend
   heap         THE public allocator surface: AllocRequest/AllocResponse
                protocol, `step`, `MultiCoreHeap` (vmap over cores)
   design_space Table 1 / Fig 5 exploration

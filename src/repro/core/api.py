@@ -154,8 +154,8 @@ class HeapClient:
     def gc(self) -> None:
         """Merge fully-free thread-cache blocks back into the buddy.
 
-        Works on every pim-style kind (sw/hwsw/pallas/sanitizer/arena/
-        tlregion share the PimMallocState layout in `.alloc` — the
+        Works on every pim-style kind (sw/hwsw/sanitizer/arena/tlregion
+        share the PimMallocState layout in `.alloc` — the
         sanitizer's shadow map and quarantine describe live allocations,
         which gc never moves, and the arena kinds' bump region lives
         outside the backend's thread caches entirely); strawman has no

@@ -20,10 +20,7 @@ step function:
 
 Everything the arena does not own — big allocs, arena exhaustion
 (spill-to-buddy), non-arena pointers — is forwarded verbatim to the full
-hwsw stack (freelists + buddy + metadata cache), or to the fused Pallas
-kernel when ``SystemConfig.arena_inner == "pallas"``; the two inner
-backends are bitwise-identical, so the kernel parity guarantee composes
-through this layer unchanged.
+hwsw stack (freelists + buddy + metadata cache).
 
 Layout and conservation: the region occupies ``[0, arena_bytes)`` (the
 leftmost-descent buddy hands a pristine heap's first ``heap_bytes // 2``
@@ -42,8 +39,7 @@ from __future__ import annotations
 from typing import NamedTuple
 
 import jax.numpy as jnp
-
-from repro.kernels import freelist
+from jax import lax
 
 from . import buddy, cost_model, pim_malloc
 from .heap import (OP_CALLOC, OP_EPOCH_RESET, OP_FREE, OP_MALLOC, OP_NOOP,
@@ -62,6 +58,71 @@ def arena_bytes(cfg) -> int:
     assert ab % cfg.pm.block_bytes == 0, \
         f"arena region {ab} must be block-aligned ({cfg.pm.block_bytes})"
     return ab
+
+
+# ---------------------------------------------------------------------------
+# arena frontend primitives (the bump-pointer fast path fused ahead of the
+# buddy mutex phase). Pure jnp so they trace inside jitted step bodies and
+# stay visible to the pimcheck verifier passes.
+# ---------------------------------------------------------------------------
+def arena_bump_shared(bump, cand, gneed, limit: int):
+    """Shared-arena bump allocation: contenders serialize in thread order.
+
+    bump: int32[] granules consumed; cand: bool[T] attempts this round;
+    gneed: int32[T] granules wanted. A failed fit does NOT consume space —
+    a later, smaller request can still be served (hence the scan, which is
+    also the modeled serialization point of the shared atomic add).
+    Returns (new_bump, start_granule int32[T] (-1 on fail), served bool[T]).
+    """
+
+    def body(b, x):
+        want, need = x
+        fits = want & (b + need <= limit)
+        g0 = jnp.where(fits, b, jnp.int32(-1))
+        return b + jnp.where(fits, need, 0), (g0, fits)
+
+    bump, (g0, served) = lax.scan(body, bump, (cand, gneed))
+    return bump, g0, served
+
+
+def arena_bump_tl(bump, cand, gneed, region_gran: int):
+    """Per-thread-region bump allocation: fully vectorized, no cross-thread
+    serialization (the tlregion fast path). ``bump`` is int32[T], each entry
+    an offset inside thread t's private region of ``region_gran`` granules.
+    Returns (new_bump, absolute start granule int32[T] (-1 on fail), served).
+    """
+    T = bump.shape[0]
+    fits = cand & (bump + gneed <= region_gran)
+    base = jnp.arange(T, dtype=jnp.int32) * region_gran
+    g0 = jnp.where(fits, base + bump, jnp.int32(-1))
+    return bump + jnp.where(fits, gneed, 0), g0, fits
+
+
+def arena_mark(cls_map, g, cls, on):
+    """Record an arena placement: cls_map[g] = cls where ``on`` (scatter with
+    an out-of-bounds park slot for masked threads, drop-guarded)."""
+    n = cls_map.shape[0]
+    idx = jnp.where(on, jnp.clip(g, 0, n - 1), jnp.int32(n))
+    return cls_map.at[idx].set(jnp.where(on, cls, jnp.int32(-1)), mode="drop")
+
+
+def arena_hole(cls_map, g, on):
+    """Retire an arena block: cls_map[g] = -1 where ``on`` (bump space is
+    not reclaimed until the next epoch reset — holes stay holes)."""
+    n = cls_map.shape[0]
+    idx = jnp.where(on, jnp.clip(g, 0, n - 1), jnp.int32(n))
+    return cls_map.at[idx].set(jnp.int32(-1), mode="drop")
+
+
+def arena_region_reset(cls_map, class_sizes, region_mask):
+    """Bulk epoch reset over ``region_mask`` granules: clears every placement
+    in the region and returns (new_cls_map, freed_bytes) where freed_bytes
+    is the rounded occupancy being retired (the telemetry delta)."""
+    nc = class_sizes.shape[0]
+    live = region_mask & (cls_map >= 0)
+    freed = jnp.sum(jnp.where(
+        live, class_sizes[jnp.clip(cls_map, 0, nc - 1)], 0))
+    return jnp.where(region_mask, jnp.int32(-1), cls_map), freed
 
 
 def n_granules(cfg) -> int:
@@ -131,8 +192,7 @@ def step(cfg, st: ArenaSystemState, req: AllocRequest, inner_step):
     """One layered protocol round: arena pass, then the forwarded backend
     round, then the merge.
 
-    ``inner_step`` is the spill backend (`system._step_pim` or
-    `system._step_pallas`). Phases:
+    ``inner_step`` is the spill backend (`system._step_pim`). Phases:
 
       0. EPOCH_RESET applies at round start (shared: any resetting thread
          clears the whole arena, idempotently; tlregion: each resetting
@@ -170,7 +230,7 @@ def step(cfg, st: ArenaSystemState, req: AllocRequest, inner_step):
         any_reset = jnp.any(is_reset)
         reset_gran = jnp.broadcast_to(any_reset, (n_gran,))
         bump = jnp.where(any_reset, 0, st.bump)
-    cls_map, reset_freed = freelist.arena_region_reset(
+    cls_map, reset_freed = arena_region_reset(
         st.cls_map, class_sizes, reset_gran)
     epoch = st.epoch + jnp.any(is_reset).astype(jnp.int32)
 
@@ -198,11 +258,11 @@ def step(cfg, st: ArenaSystemState, req: AllocRequest, inner_step):
     plain_small = is_alloc & small
     bump_cand = plain_small | (re_move & small)
     if tl:
-        bump, g_new, served = freelist.arena_bump_tl(
+        bump, g_new, served = arena_bump_tl(
             bump, bump_cand, gneed, region_gran)
         bump_wait = jnp.zeros_like(size, jnp.float32)
     else:
-        b, g_new, served = freelist.arena_bump_shared(
+        b, g_new, served = arena_bump_shared(
             bump[0], bump_cand, gneed, n_gran)
         bump = jnp.reshape(b, (1,))
         # every attempter serializes on the shared atomic add, served or not
@@ -227,9 +287,9 @@ def step(cfg, st: ArenaSystemState, req: AllocRequest, inner_step):
 
     # ---- phase 3: merge ----------------------------------------------------
     move_ok = re_move_bump | (move_to_inner & r.ok)
-    cls_map = freelist.arena_mark(cls_map, g_new, cls,
+    cls_map = arena_mark(cls_map, g_new, cls,
                                   arena_alloc | re_move_bump)
-    cls_map = freelist.arena_hole(cls_map, g_old, arena_free | move_ok)
+    cls_map = arena_hole(cls_map, g_old, arena_free | move_ok)
 
     new_ptr = g_new * GRANULE
     passthrough = ~consumed & ~move_to_inner

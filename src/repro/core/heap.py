@@ -1,8 +1,8 @@
 """The transform-native allocator surface: one request/response protocol.
 
 Every allocator design point in this repo (``strawman``, ``sw``, ``hwsw``,
-``pallas`` — the fused-kernel fast path — and ``sanitizer``, the
-shadow-heap misuse detector) serves the same typed protocol:
+``sanitizer`` — the shadow-heap misuse detector — and the ``arena`` /
+``tlregion`` bump-pointer frontends) serves the same typed protocol:
 
     state, response = heap.step(cfg, state, request)
 
@@ -214,7 +214,7 @@ def kinds() -> tuple:
 
 def _ensure_backends():
     if not REGISTRY:
-        from . import system  # noqa: F401  (registers strawman/sw/hwsw/pallas)
+        from . import system  # noqa: F401  (registers every kind)
 
 
 def init(cfg, prepopulate: bool = True):

@@ -7,10 +7,6 @@
              coarse SW metadata buffer. (Section 4.1.)
   hwsw     : PIM-malloc-HW/SW — same frontend/backend, but backend metadata
              served by the 16-entry LRU hardware buddy cache. (Section 4.2.)
-  pallas   : hwsw semantics served by ONE fused Pallas kernel per core
-             (`repro.kernels.heap_step`): VMEM-resident freelist cache +
-             in-kernel buddy traversal + in-kernel LRU buddy cache.
-             Bitwise-equal to hwsw in interpret mode; the device fast path.
   sanitizer: hwsw wrapped in a shadow map + quarantine ring
              (`repro.core.sanitizer`) — turns double-free /
              use-after-free / realloc-after-free / wild pointers into
@@ -191,17 +187,6 @@ class SystemConfig:
     sw_buf: SWBufferConfig = SWBufferConfig()
     bc: BuddyCacheConfig = BuddyCacheConfig()
     dpu: DPUCost = DPUCost()
-    # ``pallas`` kind only: batched same-class backend refill inside the
-    # fused kernel. None defers to PIM_MALLOC_BATCH_REFILL (default on);
-    # False forces the pre-batching serial walk. Bitwise-identical either
-    # way — this is a wall-clock knob, not a semantic one.
-    kernel_batch_refill: bool = None
-    # ``arena``/``tlregion`` kinds only: which backend serves arena spills —
-    # "hwsw" (scan-based reference) or "pallas" (the fused kernel under the
-    # existing 3-way refill switch). Bitwise-identical either way (the
-    # kernel parity guarantee composes through the arena layer; pinned in
-    # tests/test_kind_conformance.py).
-    arena_inner: str = "hwsw"
 
     def __post_init__(self):
         heap._ensure_backends()
@@ -221,18 +206,18 @@ class SystemConfig:
 
     @property
     def access_fn(self):
-        if self.kind in ("hwsw", "pallas", "sanitizer", "arena", "tlregion"):
+        if self.kind in ("hwsw", "sanitizer", "arena", "tlregion"):
             return functools.partial(buddy_cache_access, self.bc)
         return functools.partial(sw_buffer_access, self.sw_buf)
 
     def cache_init(self):
-        if self.kind in ("hwsw", "pallas", "sanitizer", "arena", "tlregion"):
+        if self.kind in ("hwsw", "sanitizer", "arena", "tlregion"):
             return buddy_cache_init(self.bc)
         return sw_buffer_init(self.sw_buf)
 
     @property
     def dma_bytes_per_miss(self) -> int:
-        if self.kind in ("hwsw", "pallas", "sanitizer", "arena", "tlregion"):
+        if self.kind in ("hwsw", "sanitizer", "arena", "tlregion"):
             return buddy_cache.WORD_BYTES
         return self.sw_buf.line_bytes
 
@@ -250,7 +235,7 @@ class HeapTelemetry(NamedTuple):
     holds after every round (pinned in tests/test_telemetry.py); the two
     snapshot terms come from `repro.core.telemetry`. Both counters are
     identical across backends — the deltas are computed in `_price_round`,
-    which every kind (including ``pallas``) goes through.
+    which every kind goes through.
     """
 
     live_bytes: jnp.ndarray  # int32[] rounded bytes currently handed out
@@ -401,11 +386,7 @@ def _price_round(cfg: SystemConfig, req: AllocRequest, *, mptrs, m_path,
     freed_bytes) — the heap-telemetry deltas of the round in rounded
     allocator bytes (see :class:`HeapTelemetry`).
 
-    Shared by every backend: the scan-based rounds feed it the metadata
-    cache sim's per-op stats, the ``pallas`` backend feeds it the fused
-    kernel's in-kernel counters. Identical counters => identical latencies
-    and telemetry, which is what pins the kernel path bitwise to the
-    ``hwsw`` reference.
+    Every backend's round feeds it the metadata cache sim's per-op stats.
     """
     op, size, ptr = req.op, req.size, req.ptr
     is_alloc = (op == OP_MALLOC) | (op == OP_CALLOC)
@@ -531,8 +512,7 @@ def _step_sanitizer(cfg: SystemConfig, st, req: AllocRequest):
 def _arena_step_compiled(cfg: SystemConfig, st, req: AllocRequest):
     from . import arena
 
-    inner = _step_pallas if cfg.arena_inner == "pallas" else _step_pim
-    return arena.step(cfg, st, req, inner)
+    return arena.step(cfg, st, req, _step_pim)
 
 
 @heap.register("arena")
@@ -544,97 +524,13 @@ def _step_arena(cfg: SystemConfig, st, req: AllocRequest):
     bumping into a region carved out of the buddy heap at init, retires
     whole epochs with OP_EPOCH_RESET, and forwards everything else — big
     allocs, non-arena pointers, and spill-on-exhaustion — to the full
-    hwsw stack (`_step_pim`, or the fused kernel when
-    ``cfg.arena_inner == "pallas"``). ``arena`` shares one region (bump
+    hwsw stack (`_step_pim`). ``arena`` shares one region (bump
     adds serialize for cyc_bump_atomic each); ``tlregion`` gives each
     thread its own region and per-thread resets — no cross-thread atomic
     on the fast path. Jit-compiled as one unit for the same reason as the
     sanitizer step.
     """
     return _arena_step_compiled(cfg, st, req)
-
-
-@heap.register("pallas")
-def _step_pallas(cfg: SystemConfig, st: SystemState, req: AllocRequest):
-    """The fused-kernel design point: hwsw semantics, one Pallas call.
-
-    The whole round (dispatch + thread-cache frontend + serial buddy backend
-    + LRU buddy cache) runs inside `repro.kernels.heap_step`; this wrapper
-    only rebuilds the state pytree, folds the kernel's per-thread records
-    into the allocator stats, and prices the round through the same
-    `_price_round` as the scan-based backends. State layout is identical to
-    ``hwsw`` (PimMallocState + BuddyCacheState), and results are bitwise
-    equal to it — pinned in tests/test_pallas_heap.py.
-    """
-    from repro.kernels import heap_step
-
-    pmc = cfg.pm
-    al, ca = st.alloc, st.cache
-    out = heap_step.fused_heap_step(
-        req.op, req.size, req.ptr, al.buddy.longest, al.counts, al.stacks,
-        al.block_cls, al.block_free, al.big_log2, ca.tags, ca.last_used,
-        jnp.reshape(ca.clock, (1,)), heap_bytes=pmc.heap_bytes,
-        block_bytes=pmc.block_bytes, size_classes=pmc.size_classes,
-        batch_refill=cfg.kernel_batch_refill)
-
-    m_hit = out.m_hit.astype(bool)
-    m_refill = out.m_refill.astype(bool)
-    m_bypass = out.m_bypass.astype(bool)
-    m_okb = out.m_okb.astype(bool)
-    f_push = out.f_push.astype(bool)
-    f_big = out.f_big.astype(bool)
-    in_place = out.in_place.astype(bool)
-    moved = out.moved_raw.astype(bool)
-    valid_old = out.valid_old.astype(bool)
-
-    need = m_refill | m_bypass
-    is_alloc = (req.op == OP_MALLOC) | (req.op == OP_CALLOC)
-    m_active = (is_alloc & (req.size > 0)) | moved
-    too_big = m_active & (req.size > pmc.heap_bytes)
-    m_path = jnp.where(
-        m_hit, 0,
-        jnp.where(m_refill & m_okb, 1,
-                  jnp.where(m_bypass & m_okb, 2,
-                            jnp.where(need | too_big, 3, INVALID)))
-    ).astype(jnp.int32)
-    mok = m_active & (out.m_ptr >= 0)
-    re_free0 = (req.op == OP_REALLOC) & (req.size <= 0) & (req.ptr >= 0)
-    # same misuse accounting as pim_malloc.free: every requested free that
-    # neither pushed nor reached the buddy is dropped (NULL == -1 exempt)
-    f_active = (req.op == OP_FREE) | (moved & valid_old & mok) | re_free0
-    f_drop = f_active & (req.ptr != -1) & ~f_push & ~f_big
-    fpath = jnp.where(f_push, 0,
-                      jnp.where(f_big, 1,
-                                jnp.where(f_drop, 2, INVALID))).astype(jnp.int32)
-
-    stats = al.stats._replace(
-        front_hits=al.stats.front_hits + jnp.sum(m_hit),
-        front_misses=al.stats.front_misses + jnp.sum(m_refill),
-        bypass=al.stats.bypass + jnp.sum(m_bypass),
-        fails=al.stats.fails + jnp.sum((need & ~m_okb) | too_big),
-        frees_small=al.stats.frees_small + jnp.sum(f_push),
-        frees_big=al.stats.frees_big + jnp.sum(f_big),
-        dropped_frees=al.stats.dropped_frees + jnp.sum(f_drop),
-    )
-    new_alloc = pim_malloc.PimMallocState(
-        buddy=BuddyState(longest=out.longest), counts=out.counts,
-        stacks=out.stacks, block_cls=out.block_cls,
-        block_free=out.block_free, big_log2=out.big_log2, stats=stats)
-    new_cache = buddy_cache.BuddyCacheState(
-        tags=out.tags, last_used=out.last_used,
-        clock=jnp.reshape(out.clock, ()))
-
-    dma = cfg.dma_bytes_per_miss
-    resp, alloc_bytes, freed_bytes = _price_round(
-        cfg, req, mptrs=out.m_ptr, m_path=m_path, m_bpos=out.m_bpos,
-        m_lvdown=out.m_lvdown, m_lvup=out.m_lvup, fpath=fpath,
-        f_bpos=out.f_bpos, f_lvup=out.f_lvup,
-        hits_m=out.m_hits, miss_m=out.m_miss, dram_m=out.m_miss * dma,
-        hits_f=out.f_hits, miss_f=out.f_miss, dram_f=out.f_miss * dma,
-        in_place=in_place, moved=moved, mok=mok, valid_old=valid_old,
-        old_bytes=out.old_bytes, new_bytes=out.new_bytes, re_free0=re_free0)
-    telem = _advance_telemetry(st.telem, alloc_bytes, freed_bytes)
-    return SystemState(alloc=new_alloc, cache=new_cache, telem=telem), resp
 
 
 def _round_info(resp: AllocResponse) -> RoundInfo:

@@ -9,9 +9,8 @@ from __future__ import annotations
 import functools
 
 import jax
-import jax.numpy as jnp
 
-from . import buddy_traverse, flash_attention, freelist, paged_attention, ref
+from . import flash_attention, paged_attention, ref
 
 
 def on_tpu() -> bool:
@@ -20,30 +19,6 @@ def on_tpu() -> bool:
 
 def _interp(interpret):
     return (not on_tpu()) if interpret is None else interpret
-
-
-@functools.partial(jax.jit, static_argnames=("heap_bytes", "min_block", "interpret"))
-def buddy_alloc_batch(tree, sizes, *, heap_bytes: int, min_block: int,
-                      interpret: bool | None = None):
-    """[C, B] buddy allocations over [C, n_nodes] trees (VMEM-resident)."""
-    B = sizes.shape[1]
-    pad = (-B) % 128  # lane-align the request batch for TPU
-    if pad:
-        sizes = jnp.pad(sizes, ((0, 0), (0, pad)))  # size 0 -> rounded to min,
-        # but guarded: 0-size requests still allocate min_block; mask instead:
-        sizes = sizes.at[:, B:].set(0)
-    offs, new_tree = buddy_traverse.buddy_alloc_batch_kernel(
-        tree, jnp.where(sizes > 0, sizes, 0),
-        heap_bytes=heap_bytes, min_block=min_block, interpret=_interp(interpret),
-    )
-    return offs[:, :B], new_tree
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def freelist_op(stacks, counts, op, cls, ptr_in, *, interpret: bool | None = None):
-    return freelist.freelist_op_kernel(
-        stacks, counts, op, cls, ptr_in, interpret=_interp(interpret)
-    )
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -55,8 +30,6 @@ def paged_attention_op(q, k_pages, v_pages, page_table, seq_lens, *,
 
 
 # re-exported oracles for tests/benchmarks
-buddy_alloc_batch_ref = ref.buddy_alloc_batch_ref
-freelist_op_ref = ref.freelist_op_ref
 paged_attention_ref = ref.paged_attention_ref
 
 

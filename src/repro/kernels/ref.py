@@ -4,43 +4,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.core import buddy
-from repro.core.buddy import BuddyConfig, BuddyState
-
-
-def buddy_alloc_batch_ref(tree, sizes, *, heap_bytes: int, min_block: int):
-    """Reference for kernels.buddy_traverse: vmapped scan of core.buddy.alloc."""
-    cfg = BuddyConfig(heap_bytes=heap_bytes, min_block=min_block)
-
-    def per_core(tree_row, sizes_row):
-        st = BuddyState(longest=tree_row)
-        st, offs, _ = buddy.alloc_batch(cfg, st, sizes_row)
-        return offs, st.longest
-
-    offs, new_tree = jax.vmap(per_core)(tree, sizes)
-    return offs, new_tree
-
-
-def freelist_op_ref(stacks, counts, op, cls, ptr_in):
-    """Reference for kernels.freelist: vectorized pop/push per thread."""
-    T, NC, CAP = stacks.shape
-    t = jnp.arange(T)
-    c = jnp.maximum(cls, 0)
-    cnt = counts[t, c]
-    is_pop = (op == 0) & (cnt > 0)
-    is_push = (op == 1) & (cnt < CAP)
-
-    pos_pop = jnp.maximum(cnt - 1, 0)
-    ptr_out = jnp.where(is_pop, stacks[t, c, pos_pop], -1).astype(jnp.int32)
-
-    pos_push = jnp.minimum(cnt, CAP - 1)
-    new_stacks = stacks.at[t, c, pos_push].set(
-        jnp.where(is_push, ptr_in, stacks[t, c, pos_push])
-    )
-    delta = jnp.where(is_pop, -1, jnp.where(is_push, 1, 0))
-    new_counts = counts.at[t, c].add(delta)
-    return ptr_out, new_counts, new_stacks
-
 
 def paged_attention_ref(q, k_pages, v_pages, page_table, seq_lens):
     """Reference for kernels.paged_attention: dense gather + masked softmax."""

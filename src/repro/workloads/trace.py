@@ -8,7 +8,7 @@ round that *produced* the pointer being operated on (-1 = use the raw
 recorded value, e.g. a NULL or a deliberately bogus pointer). Replay
 (`repro.workloads.replay`) resolves refs against the pointers the *target*
 backend actually returned, so one tape drives every `heap.REGISTRY` kind
-closed-loop: ``sw``/``hwsw``/``pallas`` reproduce the recorded pointer
+closed-loop: ``sw``/``hwsw`` reproduce the recorded pointer
 stream bitwise, and ``strawman`` serves the same workload shape through its
 own placements.
 

@@ -2,9 +2,8 @@
 
 The script itself refuses to run without a TPU; these tests drive its phase
 functions directly (JAX_PLATFORMS=cpu): the fleet session and its checks,
-the op-for-op oracle comparison (and that it catches a wrong pointer), the
-pallas interpret refusal, and the rank-sharded phase on four virtual CPU
-devices in a subprocess.
+the op-for-op oracle comparison (and that it catches a wrong pointer), and
+the rank-sharded phase on four virtual CPU devices in a subprocess.
 """
 import dataclasses
 import os
@@ -96,17 +95,6 @@ def test_oracle_check_catches_a_wrong_pointer():
     host["ptr"][r, 0, 0, t] += 16
     with pytest.raises(cs.SmokeFailure, match="field ptr"):
         cs.check_oracle(engine, plan, host, 0, 0)
-
-
-def test_pallas_interpret_refused_on_tpu_backend(monkeypatch):
-    from repro.kernels import ops
-    monkeypatch.setattr(ops, "on_tpu", lambda: True)
-    assert "ROADMAP A2" in cs.check_pallas_refuses_interpret()
-
-
-def test_pallas_check_fails_when_the_interpreter_runs():
-    with pytest.raises(cs.SmokeFailure, match="interpreter ran"):
-        cs.check_pallas_refuses_interpret()
 
 
 SHARDED = r"""

@@ -1,6 +1,6 @@
 """The CI workflows are config-as-code: pin their syntax and the invariants
-this repo's lanes rely on (bench-wall step, nightly dispatchability, pip
-caching) so a stray YAML edit fails tier-1 instead of the first push."""
+this repo's lanes rely on (the bench-smoke gate, nightly dispatchability,
+pip caching) so a stray YAML edit fails tier-1 instead of the first push."""
 import os
 
 import pytest
@@ -55,8 +55,8 @@ def test_tier1_matrix_has_chaos_smoke_lane():
 
 def test_tier1_fuzz_smoke_lane_runs_kind_conformance():
     """Acceptance: the registry-generic conformance suite (which enrolls
-    arena/tlregion in conservation, C-edges, digest-stability, and
-    arena-inner parity) rides the fuzz-smoke lane on every PR."""
+    arena/tlregion in conservation, C-edges and digest-stability) rides
+    the fuzz-smoke lane on every PR."""
     job = _load("ci.yml")["jobs"]["tier1"]
     lanes = {e["suite"]: e["run"]
              for e in job["strategy"]["matrix"]["include"]}
@@ -72,19 +72,20 @@ def test_analysis_lane_has_region_frontend_pimcheck_cell():
     assert "--tiers single,vmap,sharded" in text
 
 
-def test_bench_smoke_job_runs_wall_lane_and_both_gates():
-    """Acceptance: the bench-wall step runs the wall-clock lane, the wall
-    gate is exercised (not skipped) with --lane wall, and the JSON rides
-    the uploaded artifact."""
+def test_bench_smoke_job_runs_the_modeled_gate():
+    """Acceptance: bench-smoke writes the schema'd smoke JSON, fails on
+    /ERROR rows, gates it with --lane modeled against the committed
+    BENCH_BASELINE.json, and uploads the JSON as the run's artifact."""
     job = _load("ci.yml")["jobs"]["bench-smoke"]
     text = _run_text(job)
-    assert "fig14_wall" in text and "bench_wall.json" in text
-    assert "--lane wall" in text and "--fail-over-wall" in text
-    assert "--lane modeled" in text
-    assert "wall_report.py" in text
+    assert "benchmarks.run --smoke" in text and "bench_smoke.json" in text
+    assert '"/ERROR"' in text
+    gate = [line for line in text.splitlines() if "perf_gate.py" in line]
+    assert gate and "bench_smoke.json" in gate[0]
+    assert "--baseline BENCH_BASELINE.json --lane modeled" in text
     upload = [s for s in _steps(job)
               if "upload-artifact" in str(s.get("uses", ""))]
-    assert upload and "bench_wall.json" in upload[0]["with"]["path"]
+    assert upload and "bench_smoke.json" in upload[0]["with"]["path"]
 
 
 def test_nightly_workflow_scheduled_and_dispatchable():

@@ -8,14 +8,13 @@ NULL and garbage pointers, cross-round double frees, realloc-after-free,
 zero/negative/overflowing sizes, and capacity-exhausting bursts. Every
 stream must satisfy the repo's established contract:
 
-  * ``pallas`` == ``hwsw`` bitwise on the full response stream,
   * ``sw`` == ``hwsw`` on the semantic fields (ptr/ok/path/moved),
   * heap-telemetry conservation holds for every kind (strawman included),
   * ``hwsw`` == the plain-Python `PyPimMalloc.request` oracle
     pointer-for-pointer, with conservation checked after every round.
 
 Two deliberate generator constraints, both excluding C-level data races no
-backend promises to price consistently (all four kinds still agree with
+backend promises to price consistently (all the kinds still agree with
 each other on them — only the *conservation accounting* is off, because a
 round is priced against its pre-round metadata):
 
@@ -236,9 +235,6 @@ def assert_stream_contract(trace: Trace, kinds=None):
     for kind, rep in reps.items():
         assert rep["telemetry"]["conservation_residual"] == 0, \
             f"{trace.name}/{kind}: conservation violated"
-    if "pallas" in reps and "hwsw" in reps:
-        assert reps["pallas"]["digest_full"] == reps["hwsw"]["digest_full"], \
-            f"{trace.name}: pallas != hwsw bitwise"
     if "sw" in reps and "hwsw" in reps:
         assert reps["sw"]["digest_sem"] == reps["hwsw"]["digest_sem"], \
             f"{trace.name}: sw != hwsw on semantic fields"
@@ -251,9 +247,9 @@ def assert_stream_contract(trace: Trace, kinds=None):
 @pytest.mark.parametrize("seed", SMOKE_SEEDS)
 def test_fuzz_misuse_stream_contract(seed):
     """Misuse streams (double frees, garbage pointers, realloc-after-free)
-    through the pim family: sw/hwsw/pallas parity + conservation."""
+    through the pim family: sw/hwsw parity + conservation."""
     trace = fuzz_trace(seed)
-    reps = assert_stream_contract(trace, kinds=("sw", "hwsw", "pallas"))
+    reps = assert_stream_contract(trace, kinds=("sw", "hwsw"))
     # the streams genuinely exercise the nasty paths
     assert reps["hwsw"]["dropped_frees"] > 0, "no misuse generated?"
     assert reps["hwsw"]["ops"] > SMOKE_ROUNDS  # multi-op rounds
@@ -261,7 +257,7 @@ def test_fuzz_misuse_stream_contract(seed):
 
 @pytest.mark.parametrize("seed", SMOKE_SEEDS)
 def test_fuzz_clean_stream_contract_all_kinds(seed):
-    """Well-formed streams through ALL four kinds (strawman included):
+    """Well-formed streams through every registered kind (strawman included):
     parity + conservation on every backend."""
     trace = fuzz_trace(seed + 100, clean=True)
     reps = assert_stream_contract(trace)
@@ -409,9 +405,9 @@ def test_fuzz_arena_oracle_differential(kind, seed):
 @settings(max_examples=MAX_EXAMPLES, deadline=None, derandomize=True)
 @given(st.integers(0, 2**31 - 1))
 def test_property_stream_contract(seed):
-    """Any seed's stream satisfies sw/hwsw/pallas parity + conservation."""
+    """Any seed's stream satisfies sw/hwsw parity + conservation."""
     assert_stream_contract(fuzz_trace(seed, rounds=20),
-                           kinds=("sw", "hwsw", "pallas"))
+                           kinds=("sw", "hwsw"))
 
 
 @settings(max_examples=max(MAX_EXAMPLES // 3, 3), deadline=None,

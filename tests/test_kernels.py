@@ -1,75 +1,10 @@
 """Per-kernel validation: Pallas (interpret mode) vs pure-jnp ref oracles,
 with shape/dtype sweeps."""
-import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import buddy
-from repro.core.buddy import BuddyConfig
 from repro.kernels import ops
-
-
-# ------------------------------------------------------------- buddy_traverse
-@pytest.mark.parametrize("heap,min_block", [(1 << 14, 32), (1 << 16, 64),
-                                            (1 << 18, 4096)])
-@pytest.mark.parametrize("cores,batch", [(1, 8), (4, 16)])
-def test_buddy_traverse_matches_ref(heap, min_block, cores, batch):
-    cfg = BuddyConfig(heap_bytes=heap, min_block=min_block)
-    tree = jax.vmap(lambda _: buddy.init(cfg).longest)(jnp.arange(cores))
-    rng = np.random.RandomState(0)
-    sizes = jnp.asarray(
-        rng.choice([min_block, min_block * 2, min_block * 7, heap // 8],
-                   size=(cores, batch)), jnp.int32)
-    offs_k, tree_k = ops.buddy_alloc_batch(
-        tree, sizes, heap_bytes=heap, min_block=min_block, interpret=True)
-    offs_r, tree_r = ops.buddy_alloc_batch_ref(
-        tree, sizes, heap_bytes=heap, min_block=min_block)
-    np.testing.assert_array_equal(np.asarray(offs_k), np.asarray(offs_r))
-    np.testing.assert_array_equal(np.asarray(tree_k), np.asarray(tree_r))
-
-
-def test_buddy_traverse_exhaustion():
-    heap, mb = 1 << 12, 32
-    cfg = BuddyConfig(heap_bytes=heap, min_block=mb)
-    tree = buddy.init(cfg).longest[None]
-    sizes = jnp.full((1, 40), 128, jnp.int32)  # 40*128 > 4096 -> some fail
-    offs, _ = ops.buddy_alloc_batch(tree, sizes, heap_bytes=heap, min_block=mb,
-                                    interpret=True)
-    offs = np.asarray(offs)[0]
-    assert (offs >= 0).sum() == heap // 128
-    assert (offs[heap // 128:] == -1).all()
-
-
-# ------------------------------------------------------------------ freelist
-@pytest.mark.parametrize("T,NC,CAP", [(4, 8, 64), (8, 4, 128)])
-def test_freelist_matches_ref(T, NC, CAP):
-    rng = np.random.RandomState(1)
-    counts = jnp.asarray(rng.randint(0, CAP, size=(T, NC)), jnp.int32)
-    stacks = jnp.asarray(rng.randint(0, 1 << 20, size=(T, NC, CAP)), jnp.int32)
-    for trial in range(3):
-        op = jnp.asarray(rng.randint(-1, 2, size=(T,)), jnp.int32)
-        cls = jnp.asarray(rng.randint(0, NC, size=(T,)), jnp.int32)
-        ptr = jnp.asarray(rng.randint(0, 1 << 20, size=(T,)), jnp.int32)
-        pk, ck, sk = ops.freelist_op(stacks, counts, op, cls, ptr, interpret=True)
-        pr, cr, sr = ops.freelist_op_ref(stacks, counts, op, cls, ptr)
-        np.testing.assert_array_equal(np.asarray(pk), np.asarray(pr))
-        np.testing.assert_array_equal(np.asarray(ck), np.asarray(cr))
-        np.testing.assert_array_equal(np.asarray(sk), np.asarray(sr))
-        stacks, counts = sk, ck
-
-
-def test_freelist_pop_empty_and_push_full():
-    T, NC, CAP = 2, 2, 4
-    counts = jnp.array([[0, 4], [1, 4]], jnp.int32)
-    stacks = jnp.arange(T * NC * CAP, dtype=jnp.int32).reshape(T, NC, CAP)
-    op = jnp.array([0, 1], jnp.int32)      # pop empty class, push full class
-    cls = jnp.array([0, 1], jnp.int32)
-    ptr = jnp.array([111, 222], jnp.int32)
-    pk, ck, sk = ops.freelist_op(stacks, counts, op, cls, ptr, interpret=True)
-    assert int(pk[0]) == -1                    # pop from empty -> -1
-    assert int(ck[0, 0]) == 0
-    np.testing.assert_array_equal(np.asarray(sk[1, 1]), np.asarray(stacks[1, 1]))
 
 
 # ------------------------------------------------------------ paged attention

@@ -10,18 +10,12 @@ in the core contracts:
     realloc(NULL, 0) / negative sizes) served through the live heap,
   * tape-replay digest stability (same tape -> same digest, including
     through a JSON round-trip).
-
-The arena kinds additionally pin their composability axis: the forwarded
-backend is interchangeable (``arena_inner="hwsw"`` vs ``"pallas"``)
-bitwise, reset rounds included.
 """
 import jax.numpy as jnp
-import numpy as np
 import pytest
 
 from repro.core import heap, system as sysm
 from repro.core.api import HeapClient
-from repro.core.heap import AllocRequest
 from repro.workloads.replay import replay
 from repro.workloads.trace import RecordingAllocator, Trace
 
@@ -34,7 +28,7 @@ def test_registry_and_kinds_agree():
     # system.KINDS orders for presentation; the membership must match the
     # registry exactly so nothing escapes the parametrized contracts
     assert set(sysm.KINDS) == set(KINDS)
-    assert {"strawman", "sw", "hwsw", "pallas", "sanitizer", "arena",
+    assert {"strawman", "sw", "hwsw", "sanitizer", "arena",
             "tlregion"} <= set(KINDS)
 
 
@@ -123,55 +117,3 @@ def test_tape_replay_digest_stable(kind, tmp_path):
     tr.save(p)
     _, _, c = replay(Trace.load(p), kind)
     assert c["digest_full"] == a["digest_full"]
-
-
-# --------------------------------------------------------------------------
-# arena composability: the forwarded backend is interchangeable bitwise
-# --------------------------------------------------------------------------
-def _closed_loop_stream(kind: str, inner: str, rounds: int = 24,
-                        seed: int = 3):
-    cfg = sysm.SystemConfig(kind=kind, heap_bytes=HEAP, num_threads=T,
-                            arena_inner=inner)
-    st = heap.init(cfg)
-    rng = np.random.default_rng(seed)
-    live = []
-    resps = []
-    for r in range(rounds):
-        if r % 8 == 7:
-            req = heap.epoch_reset_request(T)
-            live.clear()          # reference nothing from before the reset
-        else:
-            op = rng.choice([1, 1, 2, 3, 4], size=T).astype(np.int32)
-            size = rng.choice([16, 48, 200, 2048, 4096, 8192],
-                              size=T).astype(np.int32)
-            ptr = np.full(T, -1, np.int32)
-            for t in range(T):
-                if op[t] in (2, 3) and live:
-                    ptr[t] = live.pop(int(rng.integers(len(live))))
-                elif op[t] == 2:
-                    op[t] = 0     # nothing to free: idle slot
-            req = AllocRequest(op=jnp.asarray(op), size=jnp.asarray(size),
-                               ptr=jnp.asarray(ptr))
-        st, resp = heap.step(cfg, st, req)
-        resps.append(resp)
-        rp = np.asarray(resp.ptr)
-        rok = np.asarray(resp.ok)
-        ro = np.asarray(req.op)
-        for t in range(T):
-            if rok[t] and ro[t] in (1, 3, 4) and rp[t] >= 0:
-                live.append(int(rp[t]))
-    return resps
-
-
-@pytest.mark.parametrize("kind", ("arena", "tlregion"))
-def test_arena_inner_backend_parity(kind):
-    """arena_inner='pallas' == arena_inner='hwsw' bitwise on a closed-loop
-    mixed stream with reset rounds — the frontend/backend layering is a
-    real seam, not a pair of entangled implementations."""
-    a = _closed_loop_stream(kind, "hwsw")
-    b = _closed_loop_stream(kind, "pallas")
-    for r, (ra, rb) in enumerate(zip(a, b)):
-        for f in ra._fields:
-            np.testing.assert_array_equal(
-                np.asarray(getattr(ra, f)), np.asarray(getattr(rb, f)),
-                err_msg=f"round {r} field {f}")

@@ -5,7 +5,7 @@ bursts take the buddy/bypass path while steady-state decode appends stay
 on the PAGE_UNIT frontend, eviction edges (context exactly at a page
 boundary, tenants that die mid-prefill) never allocate a page no token can
 use, mesh and vmap drivers agree bitwise, and any core's slice exports as
-a ``pim-malloc-trace/v1`` tape that replays bitwise on hwsw and pallas.
+a ``pim-malloc-trace/v1`` tape that replays bitwise on hwsw.
 Alongside it, the redesign's single-source-of-truth pins: `system.KINDS`
 derives from `heap.REGISTRY` (a freshly registered kind auto-enrolls), and
 PagePool eviction routes every free through the protocol so a double evict
@@ -226,11 +226,11 @@ def test_decode_mesh_and_vmap_paths_agree():
                                       np.asarray(getattr(rb, f)), err_msg=f)
 
 
-@pytest.mark.parametrize("kind", ["hwsw", "pallas"])
+@pytest.mark.parametrize("kind", ["hwsw"])
 def test_decode_trace_export_replays_bitwise(kind):
     """Any core's slice of the decode session exports as a
     pim-malloc-trace/v1 tape that replays bitwise through the workloads
-    engine — on the hwsw reference and the fused pallas kernel."""
+    engine."""
     eng = DecodeServe(_cfg(kind), 2, 2, traffic=_tc(rounds=20))
     plan = eng.plan()
     _, resps = eng.run(plan)
@@ -256,7 +256,7 @@ def test_decode_trace_export_replays_bitwise(kind):
 def test_kinds_derives_from_registry():
     assert tuple(sysm.KINDS) == tuple(heap.REGISTRY)
     assert set(sysm.KINDS) == set(heap.kinds())
-    assert {"sw", "hwsw", "strawman", "sanitizer", "pallas"} <= \
+    assert {"sw", "hwsw", "strawman", "sanitizer"} <= \
         set(sysm.KINDS)
 
 
